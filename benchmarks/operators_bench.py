@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.engines import DerivativeEngine, EngineSpec, NTPEngine
+from repro.core.jet import float_dtype
 from repro.core.network import make_network
 from repro.data.collocation import sample_box
 from repro.pinn.operators import get_operator, residual_values
@@ -97,8 +98,8 @@ def _time_case(op, spec: str, network: str, n_pts: int, width: int,
     net = make_network(network, d_in=op.d_in, d_out=op.d_out, width=width,
                        depth=depth)
     engine = DerivativeEngine.from_spec(spec)
-    params = net.init(jax.random.PRNGKey(0), dtype=jnp.float64)
-    x = sample_box(jax.random.PRNGKey(1), op.domain, n_pts, jnp.float64)
+    params = net.init(jax.random.PRNGKey(0), dtype=float_dtype())
+    x = sample_box(jax.random.PRNGKey(1), op.domain, n_pts)
 
     fn = jax.jit(functools.partial(
         lambda p, pts, _op, _eng, _net: residual_values(
@@ -132,8 +133,8 @@ def _time_token_case(tokens: int, width: int, trials: int) -> tuple:
     net = make_network("transformer", d_in=tokens, d_out=1, width=width,
                        depth=1)
     engine = NTPEngine("pallas")
-    params = net.init(jax.random.PRNGKey(0), dtype=jnp.float64)
-    x = jax.random.uniform(jax.random.PRNGKey(1), (2, tokens), jnp.float64,
+    params = net.init(jax.random.PRNGKey(0), dtype=float_dtype())
+    x = jax.random.uniform(jax.random.PRNGKey(1), (2, tokens), float_dtype(),
                            -1.0, 1.0)
     fn = jax.jit(lambda p, pts: engine.derivs(net, p, pts, TOKEN_AXIS_ORDER))
     t = time_fn(fn, params, x, trials=trials)
@@ -184,6 +185,9 @@ def _time_weak_case(devices: int, pts_per_device: int, width: int,
         print(json.dumps({{"s_per_call": times[len(times) // 2]}}))
     """)
     env = dict(os.environ)
+    # the children measure forced CPU host devices by design; pinning their
+    # platform keeps them off an accelerator the parent process holds
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count"
                         f"={devices}").strip()

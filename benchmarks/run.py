@@ -44,6 +44,9 @@ def main() -> None:
     if args.full and args.smoke:
         ap.error("--full and --smoke are mutually exclusive")
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (burgers_e2e, fwd_bwd, memory_scaling, operators_bench,
                    partition_growth, ratio_grid, roofline, serving_bench)
 

@@ -10,19 +10,19 @@ procedure: constrain lambda to [1/(2k+1), 1/(2k-1)], penalize
 autodiff`` runs the identical schedule with nested autodiff (the paper's
 baseline) for a wall-clock comparison; k >= 3 is where autodiff becomes
 untenable and n-TangentProp keeps going.
+
+Runs in float32, the chip's precision; ``JAX_ENABLE_X64=1`` makes a CPU run
+float64.
 """
 
 import argparse
 
 import jax
+import numpy as np
 
-jax.config.update("jax_enable_x64", True)
-
-import numpy as np  # noqa: E402
-
-from repro.pinn import (PINNRunConfig, exact_profile, profile_lambda,  # noqa: E402
-                        train)
-from repro.core.ntp import mlp_apply  # noqa: E402
+from repro.core.ntp import mlp_apply
+from repro.pinn import PINNRunConfig, exact_profile, profile_lambda, train
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -35,6 +35,7 @@ def main():
     ap.add_argument("--width", type=int, default=24)
     ap.add_argument("--depth", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = PINNRunConfig(k=args.k, engine=args.engine,
                         adam_steps=args.adam, lbfgs_steps=args.lbfgs,

@@ -24,6 +24,9 @@ platform devices via XLA_FLAGS, which is why the heavy imports happen
 *after* argument parsing.  ``--grad-compression int8|topk:F`` routes the
 gradient all-reduce through the error-feedback compressors (off by
 default: plain psum is exact).
+
+Runs in float32, the chip's precision; ``JAX_ENABLE_X64=1`` makes a CPU run
+float64.
 """
 
 import argparse
@@ -84,13 +87,12 @@ def main():
             os.environ.get("XLA_FLAGS", "") +
             f" --xla_force_host_platform_device_count={args.devices}").strip()
 
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-
     from repro.core import network_names
     from repro.pinn import (OperatorRunConfig, get_operator, operator_names,
                             train_operator)
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.op not in operator_names():
         raise SystemExit(f"unknown --op {args.op!r}; known: "

@@ -11,14 +11,17 @@ import time
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_enable_x64", True)
+from repro.core import baselines, init_mlp, ntp_derivatives
+from repro.core import jet as J
+from repro.runtime.compile_cache import enable_compile_cache
 
-from repro.core import baselines, init_mlp, ntp_derivatives  # noqa: E402
+enable_compile_cache()
+dtype = J.float_dtype()     # float32; JAX_ENABLE_X64=1 gives float64 on CPU
 
 # the paper's standard PINN network: 3 hidden layers x 24 neurons, tanh
 params = init_mlp(jax.random.PRNGKey(0), d_in=1, width=24, depth=3, d_out=1,
-                  dtype=jnp.float64)
-x = jnp.linspace(-1.0, 1.0, 256, dtype=jnp.float64)[:, None]
+                  dtype=dtype)
+x = jnp.linspace(-1.0, 1.0, 256, dtype=dtype)[:, None]
 
 N = 8
 t0 = time.perf_counter()
@@ -34,9 +37,7 @@ err = jnp.max(jnp.abs(derivs[:7, :8] - ref))
 print(f"max |ntp - nested autodiff| over orders 0..6: {err:.2e}")
 
 # jets through a full attention block work too (beyond the paper):
-from repro.core import jet as J  # noqa: E402
-
-h = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 16), jnp.float64)
-v = jax.random.normal(jax.random.PRNGKey(2), (2, 5, 16), jnp.float64)
+h = jax.random.normal(jax.random.PRNGKey(1), (2, 5, 16), dtype)
+v = jax.random.normal(jax.random.PRNGKey(2), (2, 5, 16), dtype)
 jet = J.softmax(J.seed(h, v, 4), axis=-1)
 print("4th directional derivative of softmax:", jet.coeffs[4].shape)

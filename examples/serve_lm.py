@@ -12,6 +12,7 @@ to 256/512 chips.
 import argparse
 
 from repro.launch import serve
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     import sys
     sys.argv = ["serve", "--arch", args.arch, "--batch", str(args.batch),

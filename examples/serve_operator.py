@@ -12,6 +12,9 @@ concurrent clients coalesce into shape-bucketed launches, compiled
 executables are cached per (engine, order, bucket), and each response
 carries queue-wait/pad/cache metrics.  Served tables are checked against a
 direct ``engine.grid`` call before the per-spec metrics print.
+
+Runs in float32, the chip's precision; ``JAX_ENABLE_X64=1`` makes a CPU run
+float64.
 """
 
 import argparse
@@ -19,18 +22,16 @@ import tempfile
 import threading
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 
-jax.config.update("jax_enable_x64", True)
-
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.ckpt import CheckpointManager  # noqa: E402
-from repro.core.engines import DerivativeEngine  # noqa: E402
-from repro.data.collocation import sample_box  # noqa: E402
-from repro.pinn import (OperatorRunConfig, get_operator,  # noqa: E402
-                        operator_names, train_operator)
-from repro.serving import DerivativeServer  # noqa: E402
+from repro.ckpt import CheckpointManager
+from repro.core.engines import DerivativeEngine
+from repro.data.collocation import sample_box
+from repro.pinn import (OperatorRunConfig, get_operator, operator_names,
+                        train_operator)
+from repro.runtime.compile_cache import enable_compile_cache
+from repro.serving import DerivativeServer
 
 # every registered engine spec; mirrors benchmarks/operators_bench.SPECS
 SPECS = ("ntp", "ntp/pallas", "autodiff")
@@ -51,6 +52,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None,
                     help="checkpoint directory (default: a temp dir)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     op = get_operator(args.op)
     order = args.order if args.order is not None else op.order
@@ -67,13 +69,13 @@ def main():
     print(f"  checkpointed to {ckpt_dir}")
 
     key = jax.random.PRNGKey(7)
-    queries = [sample_box(k, op.domain, args.points, jnp.float64)
+    queries = [sample_box(k, op.domain, args.points)
                for k in jax.random.split(key, args.clients)]
 
     for spec in SPECS:
         engine = DerivativeEngine.from_spec(spec)
         with DerivativeServer.from_checkpoint(
-                ckpt_dir, net, engine=spec, dtype=jnp.float64,
+                ckpt_dir, net, engine=spec,
                 flush_window_s=0.005) as server:
             results = [None] * args.clients
 

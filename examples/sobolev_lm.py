@@ -25,6 +25,7 @@ from repro.data.tokens import synthetic_batch
 from repro.launch.ntp_reg import ntp_smoothness
 from repro.models import init_model, train_loss
 from repro.optim import adam_init, adam_update
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -36,6 +37,7 @@ def main():
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--coef", type=float, default=1e-4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch).reduced()
     shape = ShapeCfg("sobolev", args.seq, args.batch, "train")

@@ -16,6 +16,6 @@ CONFIG = ArchConfig(
     d_ff=24,
     vocab=1,             # d_in = d_out = 1 (self-similar Burgers profile)
     attn_pattern=("global",),
-    dtype="float64",
+    dtype="float32",
     source="[paper section IV: 3 hidden layers x 24 neurons, tanh]",
 )

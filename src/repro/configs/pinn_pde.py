@@ -33,7 +33,7 @@ CONFIG = ArchConfig(
     d_ff=64,             # transformer feed-forward = mlp_ratio(2) * width
     vocab=2,             # d_in = 2 (t, x) or (x, y); d_out follows op.d_out
     attn_pattern=("global",),
-    dtype="float64",
+    dtype="float32",
     source="[operator subsystem default: 3 hidden layers x 32 neurons, tanh;"
            " transformer trunk: 2 heads, mlp_ratio 2 over coordinate tokens]",
 )

@@ -176,13 +176,17 @@ class DerivativeEngine:
             raise ValueError("axes must name at least one differentiation axis")
         if any(a < 0 or a >= d for a in axes):
             raise ValueError(f"axes {tuple(axes)} out of range for d_in={d}")
-        signs = jnp.asarray(list(itertools.product((1.0, -1.0), repeat=m)),
-                            x.dtype)
+        signs = list(itertools.product((1.0, -1.0), repeat=m))
         basis = jnp.eye(d, dtype=x.dtype)[jnp.asarray(axes)]   # (m, d)
-        dirs = signs @ basis                                    # (2^m, d)
+        dirs = jnp.asarray(signs, x.dtype) @ basis              # (2^m, d)
         derivs = self._batched_directional(net, params, x, dirs, m)
-        coefs = jnp.prod(signs, axis=1)                         # (2^m,)
-        top = jnp.tensordot(coefs, derivs[:, m], axes=1)        # (N, d_out)
+        # the +-1 weights are static: add the signed terms in a fixed order
+        # (not a matmul), so every launch -- one device or a mesh -- sums
+        # the same way
+        top = None                                              # (N, d_out)
+        for i, eps in enumerate(signs):
+            term = derivs[i, m] if math.prod(eps) > 0 else -derivs[i, m]
+            top = term if top is None else top + term
         return top / (2.0 ** m * math.factorial(m))
 
     # -- spec parsing -------------------------------------------------------

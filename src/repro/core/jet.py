@@ -31,6 +31,19 @@ import jax.numpy as jnp
 from .activations import GELU_TANH_C, GELU_TANH_CUBIC, TAYLOR_STACKS
 from .partitions import faa_di_bruno_table
 
+# Every contraction on the derivative path (this algebra, the Pallas kernels,
+# their oracles, the primal forwards the autodiff engine differentiates)
+# runs at full f32 precision.  A TPU's default f32 matmul may round its
+# operands to bf16, an error the order-n coefficients amplify; the engines
+# must agree to f32 rounding, not to bf16's.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def float_dtype():
+    """The widest float JAX has enabled: float64 under ``jax_enable_x64``
+    (the CPU oracle tests), float32 otherwise (the chip, which has no f64)."""
+    return jax.dtypes.canonicalize_dtype(jnp.float64)
+
 
 @jax.tree_util.register_pytree_node_class
 class Jet:
@@ -189,7 +202,7 @@ def linear(a: Jet, w: jnp.ndarray, b: jnp.ndarray | None = None,
     if not eq.startswith("..."):
         raise ValueError(f"linear eq must start with '...' so the "
                          f"coefficient axis can ride it, got {eq!r}")
-    out = jnp.einsum(eq, a.coeffs, w)
+    out = jnp.einsum(eq, a.coeffs, w, precision=MATMUL_PRECISION)
     if b is not None:
         out = out.at[0].add(b)
     return Jet(out)
@@ -235,13 +248,16 @@ def einsum(eq: str, a: JetLike, b: JetLike) -> Jet:
     If one operand is t-constant the convolution degenerates to a per-
     coefficient einsum (no extra FLOPs vs the primal op times (n+1)).
     NOTE: no broadcast alignment here -- einsum subscripts fix the ranks."""
+    def contract(x, y):
+        return jnp.einsum(eq, x, y, precision=MATMUL_PRECISION)
+
     if isinstance(a, Jet) and not isinstance(b, Jet):
-        return jmap(lambda c: jnp.einsum(eq, c, b), a)
+        return jmap(lambda c: contract(c, b), a)
     if isinstance(b, Jet) and not isinstance(a, Jet):
-        return jmap(lambda c: jnp.einsum(eq, a, c), b)
+        return jmap(lambda c: contract(a, c), b)
     if a.order != b.order:
         raise ValueError(f"jet order mismatch: {a.order} vs {b.order}")
-    return _cauchy(a, b, lambda x, y: jnp.einsum(eq, x, y))
+    return _cauchy(a, b, contract)
 
 
 # ---------------------------------------------------------------------------

@@ -57,6 +57,11 @@ from .ntp import xavier_uniform
 Params = Any  # parameter pytree; structure owned by the module
 
 
+def _matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """Primal ``x @ w`` at the derivative path's matmul precision."""
+    return jnp.matmul(x, w, precision=J.MATMUL_PRECISION)
+
+
 class Module:
     """Smallest jet-traceable unit: the Network contract without metadata.
 
@@ -193,7 +198,7 @@ class Dense(Module):
     def apply(self, params: Params, x: jnp.ndarray, *,
               unroll: bool = False) -> jnp.ndarray:
         w, b = params
-        y = x @ w + b
+        y = _matmul(x, w) + b
         return PRIMALS[self.activation](y) if self.activation else y
 
     def jet_apply(self, params: Params, jet: J.Jet, *,
@@ -247,7 +252,7 @@ class FourierFeatures(Module):
 
     def apply(self, params: Params, x: jnp.ndarray, *,
               unroll: bool = False) -> jnp.ndarray:
-        z = x @ self._freqs(params)
+        z = _matmul(x, self._freqs(params))
         return jnp.concatenate([jnp.sin(z), jnp.cos(z)], axis=-1)
 
     def jet_apply(self, params: Params, jet: J.Jet, *,
@@ -339,16 +344,18 @@ class SelfAttention(Module):
 
     def apply(self, params: Params, x: jnp.ndarray, *,
               unroll: bool = False) -> jnp.ndarray:
-        q = self._split_heads(x @ params["wq"])
-        k = self._split_heads(x @ params["wk"])
-        v = self._split_heads(x @ params["wv"])
-        s = jnp.einsum("...qhd,...khd->...hqk", q, k) / math.sqrt(self.head_dim)
+        q = self._split_heads(_matmul(x, params["wq"]))
+        k = self._split_heads(_matmul(x, params["wk"]))
+        v = self._split_heads(_matmul(x, params["wv"]))
+        s = jnp.einsum("...qhd,...khd->...hqk", q, k,
+                       precision=J.MATMUL_PRECISION) / math.sqrt(self.head_dim)
         keep = attention_mask(self.mask, x.shape[-2])
         if keep is not None:
             s = jnp.where(keep, s, jnp.asarray(J.MASK_NEG, s.dtype))
         p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("...hqk,...khd->...qhd", p, v)
-        return o.reshape(o.shape[:-2] + (self.dim,)) @ params["wo"]
+        o = jnp.einsum("...hqk,...khd->...qhd", p, v,
+                       precision=J.MATMUL_PRECISION)
+        return _matmul(o.reshape(o.shape[:-2] + (self.dim,)), params["wo"])
 
     def jet_apply(self, params: Params, jet: J.Jet, *,
                   impl: str = "jnp") -> J.Jet:

@@ -14,8 +14,9 @@ first-class abstraction: a :class:`Network` is an object with
 * ``jet_apply(params, jet, impl=)`` -- push a :class:`repro.core.jet.Jet`
   of the inputs through the network.  ``impl="jnp"`` runs the reference jet
   algebra; ``impl="pallas"`` routes every dense layer through the fused
-  Pallas kernel dispatch (kernels/ops.jet_dense), which falls back to the
-  reference automatically for activations without a kernel table.
+  Pallas kernel dispatch (kernels/ops.jet_dense); an activation without a
+  kernel table composes through the jet algebra after the kernel's linear
+  part.
 
 Every shipped network is a **thin composition over the jet-module layer**
 (:mod:`repro.core.modules`): it declares a module graph (``Sequential`` /

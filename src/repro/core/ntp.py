@@ -74,20 +74,24 @@ def mlp_apply(params: MLPParams, x: jnp.ndarray, activation: str = "tanh",
     (needed by jax.experimental.jet, which has no scan rule)."""
     from .activations import PRIMALS
     act = PRIMALS[activation]
-    h = act(x @ params.w_in + params.b_in)
+
+    def mm(a, w):
+        return jnp.matmul(a, w, precision=J.MATMUL_PRECISION)
+
+    h = act(mm(x, params.w_in) + params.b_in)
 
     if unroll:
         for i in range(params.w_hidden.shape[0]):
-            h = act(h @ params.w_hidden[i] + params.b_hidden[i])
-        return h @ params.w_out + params.b_out
+            h = act(mm(h, params.w_hidden[i]) + params.b_hidden[i])
+        return mm(h, params.w_out) + params.b_out
 
     def body(h, wb):
         w, b = wb
-        return act(h @ w + b), None
+        return act(mm(h, w) + b), None
 
     if params.w_hidden.shape[0]:
         h, _ = jax.lax.scan(body, h, (params.w_hidden, params.b_hidden))
-    return h @ params.w_out + params.b_out
+    return mm(h, params.w_out) + params.b_out
 
 
 # ---------------------------------------------------------------------------

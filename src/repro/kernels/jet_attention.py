@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.jet import MATMUL_PRECISION
+
 # Finite "minus infinity" for masked score positions: large enough that exp
 # underflows to exactly 0, small enough that (NEG - NEG) stays 0.0 and no
 # inf/NaN can enter the jet recurrences (a true -inf would produce inf-inf).
@@ -64,6 +66,7 @@ def attention_scores_jet_body(q: jnp.ndarray, k: jnp.ndarray,
         return jax.lax.dot_general(
             q[i], k[j],
             dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            precision=MATMUL_PRECISION,
             preferred_element_type=acc_t) * scale
 
     # Cauchy-convolved scores: s_k = scale * sum_{i+j=k} Q_i K_j^T
@@ -137,12 +140,14 @@ def jet_attention_scores_pallas(q: jnp.ndarray, k: jnp.ndarray, scale: float,
 # Per (batch, q-block) the kernel carries three running statistics in VMEM
 # scratch across the innermost KV grid axis:
 #
-#   m  (bb, H, bq)        -- running max of the order-0 masked scores (the
+#   m  (H, bb, bq)        -- running max of the order-0 masked scores (the
 #                            softmax shift; t-constant, so scalar per row)
-#   t  (n+1, bb, H, bq)   -- running *total* jet: sum_k e_k of the shifted
+#   t  (H, n+1, bb, bq)   -- running *total* jet: sum_k e_k of the shifted
 #                            exp jet over every key seen so far
-#   a  (n+1, bb, H, bq, D)-- running accumulator jet: the Cauchy product
+#   a  (H, n+1, bb, bq, D)-- running accumulator jet: the Cauchy product
 #                            e (*) V summed over every key seen so far
+#
+# (heads lead, so each head's statistics are one leading-axis slice)
 #
 # A shift change m -> m' rescales ALL coefficients of e by the same scalar
 # alpha = exp(m - m'): the shift is t-constant, so exp(s - m') =
@@ -182,7 +187,7 @@ def _flash_block_keep(mask: str, window: int, i, j, block_q: int,
 def _flash_kernel(q_ref, k_ref, v_ref, wo_ref, o_ref, m_ref, t_ref, a_ref, *,
                   scale, mask, window, t_k, block_q, block_k, n_kv):
     i, j = pl.program_id(1), pl.program_id(2)
-    n1 = q_ref.shape[0]
+    n1, _, n_heads = q_ref.shape[:3]
     acc_t = m_ref.dtype
     neg = jnp.asarray(MASK_NEG, acc_t)
 
@@ -195,79 +200,90 @@ def _flash_kernel(q_ref, k_ref, v_ref, wo_ref, o_ref, m_ref, t_ref, a_ref, *,
     q = q_ref[...].astype(acc_t)            # (n1, bb, H, bq, D)
     k = k_ref[...].astype(acc_t)            # (n1, bb, H, bk, D)
     v = v_ref[...].astype(acc_t)
-
-    def qk(a_i: int, b_i: int) -> jnp.ndarray:
-        # (bb, H, bq, D) x (bb, H, bk, D) -> (bb, H, bq, bk)
-        return jax.lax.dot_general(
-            q[a_i], k[b_i],
-            dimension_numbers=(((3,), (3,)), ((0, 1), (0, 1))),
-            preferred_element_type=acc_t) * scale
-
-    # Cauchy-convolved scores for this tile: s_m = scale * sum Q_i K_j^T
-    s = []
-    for m in range(n1):
-        acc = qk(0, m)
-        for a_i in range(1, m + 1):
-            acc = acc + qk(a_i, m - a_i)
-        s.append(acc)
-
     keep = _flash_block_keep(mask, window, i, j, block_q, block_k, t_k)
-    keep = keep[None, None]                 # broadcast over (bb, H)
-    s0m = jnp.where(keep, s[0], neg)
+    keep = keep[None]                       # broadcast over bb
 
-    m_old = m_ref[...]                      # (bb, H, bq)
-    m_new = jnp.maximum(m_old, jnp.max(s0m, axis=-1))
-    alpha = jnp.exp(m_old - m_new)          # rescales every e coefficient
+    # one head at a time: Mosaic's matmul takes at most one batch axis, so
+    # every contraction below batches over bb alone
+    for h in range(n_heads):
+        def qk(a_i: int, b_i: int) -> jnp.ndarray:
+            # (bb, bq, D) x (bb, bk, D) -> (bb, bq, bk)
+            return jax.lax.dot_general(
+                q[a_i, :, h], k[b_i, :, h],
+                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+                precision=MATMUL_PRECISION,
+                preferred_element_type=acc_t) * scale
 
-    # shifted exp jet for this tile; masked positions' e-jets are exactly 0:
-    # e_0 underflows (exp(NEG - m_new)) and is where'd to 0, and every
-    # higher e_m term carries an e-factor that is already 0
-    e = [jnp.where(keep, jnp.exp(s0m - m_new[..., None]), 0.0)]
-    for m in range(1, n1):
-        acc = m * s[m] * e[0]
-        for b_j in range(1, m):
-            acc = acc + b_j * s[b_j] * e[m - b_j]
-        e.append(acc / m)
+        # Cauchy-convolved scores for this tile: s_m = scale * sum Q_i K_j^T
+        s = []
+        for m in range(n1):
+            acc = qk(0, m)
+            for a_i in range(1, m + 1):
+                acc = acc + qk(a_i, m - a_i)
+            s.append(acc)
 
-    def ev(a_i: int, b_i: int) -> jnp.ndarray:
-        # (bb, H, bq, bk) x (bb, H, bk, D) -> (bb, H, bq, D)
-        return jax.lax.dot_general(
-            e[a_i], v[b_i],
-            dimension_numbers=(((3,), (2,)), ((0, 1), (0, 1))),
-            preferred_element_type=acc_t)
+        s0m = jnp.where(keep, s[0], neg)
+        m_old = m_ref[h]                    # (bb, bq)
+        m_new = jnp.maximum(m_old, jnp.max(s0m, axis=-1))
+        alpha = jnp.exp(m_old - m_new)      # rescales every e coefficient
 
-    esum, eav = [], []
-    for m in range(n1):
-        esum.append(jnp.sum(e[m], axis=-1))
-        acc = ev(0, m)
-        for a_i in range(1, m + 1):
-            acc = acc + ev(a_i, m - a_i)
-        eav.append(acc)
+        # shifted exp jet for this tile; masked positions' e-jets are exactly
+        # 0: e_0 underflows (exp(NEG - m_new)) and is where'd to 0, and every
+        # higher e_m term carries an e-factor that is already 0
+        e = [jnp.where(keep, jnp.exp(s0m - m_new[..., None]), 0.0)]
+        for m in range(1, n1):
+            acc = m * s[m] * e[0]
+            for b_j in range(1, m):
+                acc = acc + b_j * s[b_j] * e[m - b_j]
+            e.append(acc / m)
 
-    t_new = alpha[None] * t_ref[...] + jnp.stack(esum)
-    a_new = alpha[None, ..., None] * a_ref[...] + jnp.stack(eav)
-    m_ref[...] = m_new
-    t_ref[...] = t_new
-    a_ref[...] = a_new
+        def ev(a_i: int, b_i: int) -> jnp.ndarray:
+            # (bb, bq, bk) x (bb, bk, D) -> (bb, bq, D)
+            return jax.lax.dot_general(
+                e[a_i], v[b_i, :, h],
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                precision=MATMUL_PRECISION,
+                preferred_element_type=acc_t)
+
+        esum, eav = [], []
+        for m in range(n1):
+            esum.append(jnp.sum(e[m], axis=-1))
+            acc = ev(0, m)
+            for a_i in range(1, m + 1):
+                acc = acc + ev(a_i, m - a_i)
+            eav.append(acc)
+
+        m_ref[h] = m_new
+        t_ref[h] = alpha[None] * t_ref[h] + jnp.stack(esum)
+        a_ref[h] = alpha[None, ..., None] * a_ref[h] + jnp.stack(eav)
 
     @pl.when(j == n_kv - 1)
     def _epilogue():
-        # a = t (*) o  =>  o by jet division, then the output projection.
-        # t_0 >= 1 for every real query row (the row max contributes
-        # exp(0)); the floor only catches padded query rows that a local
-        # window can leave with zero kept keys, making them 0 not NaN.
-        t0 = jnp.maximum(t_new[0], jnp.asarray(1e-37, acc_t))
-        inv0 = 1.0 / t0[..., None]
-        o = [a_new[0] * inv0]
-        for m in range(1, n1):
-            acc = a_new[m]
-            for b_j in range(1, m + 1):
-                acc = acc - t_new[b_j][..., None] * o[m - b_j]
-            o.append(acc * inv0)
+        # a = t (*) o  =>  o by jet division, then the output projection,
+        # summed over heads.  t_0 >= 1 for every real query row (the row max
+        # contributes exp(0)); the floor only catches padded query rows that
+        # a local window can leave with zero kept keys, making them 0 not NaN.
         wo = wo_ref[...].astype(acc_t)      # (H, D, Dm)
-        out = [jax.lax.dot_general(
-            om, wo, dimension_numbers=(((1, 3), (0, 1)), ((), ())),
-            preferred_element_type=acc_t) for om in o]
+        out = [None] * n1
+        for h in range(n_heads):
+            t_h, a_h = t_ref[h], a_ref[h]
+            t0 = jnp.maximum(t_h[0], jnp.asarray(1e-37, acc_t))
+            inv0 = 1.0 / t0[..., None]
+            o = [a_h[0] * inv0]
+            for m in range(1, n1):
+                acc = a_h[m]
+                for b_j in range(1, m + 1):
+                    acc = acc - t_h[b_j][..., None] * o[m - b_j]
+                o.append(acc * inv0)
+            wo_h = jnp.broadcast_to(wo[h], (o[0].shape[0],) + wo.shape[1:])
+            for m in range(n1):
+                # (bb, bq, D) x (bb, D, Dm) -> (bb, bq, Dm)
+                proj = jax.lax.dot_general(
+                    o[m], wo_h,
+                    dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                    precision=MATMUL_PRECISION,
+                    preferred_element_type=acc_t)
+                out[m] = proj if out[m] is None else out[m] + proj
         o_ref[...] = jnp.stack(out).astype(o_ref.dtype)
 
 
@@ -302,9 +318,11 @@ def jet_flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if mask == "local" and window < 1:
         raise ValueError(f"local mask needs window >= 1, got {window}")
     dm = wo.shape[2]
-    bb = min(block_b, bsz)
     bq = min(block_q, t)
     bk = min(block_k, t)
+    # the per-step working set grows with bb * bq: long query blocks take
+    # fewer batch rows so the score and accumulator tiles fit in VMEM
+    bb = min(block_b, bsz, max(1, 64 // bq))
     pb, pq, pk = (-bsz) % bb, (-t) % bq, (-t) % bk
     qp = jnp.pad(q, ((0, 0), (0, pb), (0, 0), (0, pq), (0, 0)))
     kp = jnp.pad(k, ((0, 0), (0, pb), (0, 0), (0, pk), (0, 0)))
@@ -326,9 +344,9 @@ def jet_flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_specs=pl.BlockSpec((n1, bb, bq, dm), lambda b, i, j: (0, b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((n1, bsz + pb, t + pq, dm), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bb, h, bq), acc_t),
-            pltpu.VMEM((n1, bb, h, bq), acc_t),
-            pltpu.VMEM((n1, bb, h, bq, d), acc_t),
+            pltpu.VMEM((h, bb, bq), acc_t),
+            pltpu.VMEM((h, n1, bb, bq), acc_t),
+            pltpu.VMEM((h, n1, bb, bq, d), acc_t),
         ],
         interpret=interpret,
     )(qp, kp, vp, wo)

@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.jet import MATMUL_PRECISION
+
 from .tanh_jet import act_jet_body
 
 
@@ -39,14 +41,16 @@ def _kernel(y_ref, w_ref, b_ref, o_ref, acc_ref, *, activation, n_k):
     y = y_ref[...]                       # (n+1, bb, bk)
     n1, bb, bk = y.shape
     w = w_ref[...]                       # (bk, bd)
-    part = jnp.dot(y.reshape(n1 * bb, bk), w,
+    part = jnp.dot(y.reshape(n1 * bb, bk), w, precision=MATMUL_PRECISION,
                    preferred_element_type=acc_ref.dtype)
     acc_ref[...] += part.reshape(n1, bb, -1)
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         z = acc_ref[...]
-        z = z.at[0].add(b_ref[...].astype(acc_ref.dtype)[0])
+        # bias on c_0 only, as a select: Mosaic has no scatter-add lowering
+        c0 = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0) == 0
+        z = z + jnp.where(c0, b_ref[...].astype(z.dtype), 0.0)
         if activation is None:
             o_ref[...] = z.astype(o_ref.dtype)
         else:
@@ -72,12 +76,9 @@ def jet_dense_pallas(coeffs: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
     grid = (y.shape[1] // bb, wp.shape[1] // bd, wp.shape[0] // bk)
     n_k = grid[2]
 
-    try:  # dimension semantics: parallel over (B, Dout), sequential over K
-        compiler_params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except AttributeError:  # older jax
-        compiler_params = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+    # dimension semantics: parallel over (B, Dout), sequential over K
+    compiler_params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"))
 
     out = pl.pallas_call(
         functools.partial(_kernel, activation=activation, n_k=n_k),

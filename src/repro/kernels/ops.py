@@ -1,10 +1,12 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On a real TPU these run compiled; on CPU (this container) they run in
-``interpret=True`` mode, which executes the kernel body op-by-op and is what
-the allclose test sweeps exercise.  The wrappers also pick TPU-aligned block
-shapes and fall back to the pure-jnp reference for tiny shapes where a kernel
-launch would be pure overhead.
+On a TPU these run compiled; on any other backend they run in
+``interpret=True`` mode, which executes the kernel body op by op and is what
+the CPU allclose sweeps exercise.  Whether a program ran the kernels compiled
+shows in its compiled HLO (``tpu_custom_call``), which ``chip_smoke.py``
+checks.  An activation without a kernel table is an error here, never a
+silent detour through the reference: modules compose such activations
+through the jet algebra themselves (``repro.core.modules.dense_jet``).
 
 This is also the dispatch surface for the compositional module layer
 (``repro.core.modules``):
@@ -45,6 +47,13 @@ from .tanh_jet import act_jet_pallas
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _check_kernel_activation(activation: str) -> None:
+    if activation not in _KERNEL_ACTS:
+        raise ValueError(f"no Pallas Taylor table for activation "
+                         f"{activation!r} (have {_KERNEL_ACTS}); compose it "
+                         f"through repro.core.jet.activation instead")
 
 
 class EpilogueKind(enum.Enum):
@@ -108,8 +117,7 @@ def _fold_batch(coeffs: jnp.ndarray, keep: int = 1) -> tuple[jnp.ndarray, tuple]
 # ---------------------------------------------------------------------------
 
 def _act_jet_impl(coeffs: jnp.ndarray, activation: str) -> jnp.ndarray:
-    if activation not in _KERNEL_ACTS:
-        return ref.act_jet_ref(coeffs, activation)
+    _check_kernel_activation(activation)
     return act_jet_pallas(coeffs, activation, interpret=not _on_tpu())
 
 
@@ -138,8 +146,8 @@ def act_jet(coeffs: jnp.ndarray, activation: str = "tanh") -> jnp.ndarray:
 
 
 def _jet_dense_impl(coeffs, w, b, activation):
-    if activation is not None and activation not in _KERNEL_ACTS:
-        return ref.jet_dense_ref(coeffs, w, b, activation)
+    if activation is not None:
+        _check_kernel_activation(activation)
     return jet_dense_pallas(coeffs, w, b, activation, interpret=not _on_tpu())
 
 

@@ -8,13 +8,17 @@ these.
 
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
 
 from repro.core.activations import sin_taylor_stack
+from repro.core.jet import MATMUL_PRECISION
 
 from .bell_tables import fdb_terms, sigmoid_poly_rows, tanh_poly_rows
 
 _POLY_ROWS = {"tanh": tanh_poly_rows, "sigmoid": sigmoid_poly_rows}
+_einsum = functools.partial(jnp.einsum, precision=MATMUL_PRECISION)
 _PRIMAL = {"tanh": jnp.tanh, "sigmoid": lambda a: 0.5 * (jnp.tanh(0.5 * a) + 1.0)}
 
 
@@ -58,7 +62,7 @@ def jet_dense_ref(coeffs: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                   activation: str | None = "tanh") -> jnp.ndarray:
     """Fused layer oracle: (n+1, B, Din) @ (Din, Dout) + bias-on-c0, then
     the activation jet (or identity for the output layer)."""
-    z = jnp.einsum("nbi,io->nbo", coeffs, w)
+    z = _einsum("nbi,io->nbo", coeffs, w)
     z = z.at[0].add(b)
     if activation is None:
         return z
@@ -74,7 +78,7 @@ def jet_attention_scores_ref(q: jnp.ndarray, k: jnp.ndarray,
     softmax exp / sum / div power-series recurrences written out directly
     (no core.jet, no shared kernel body)."""
     n1 = q.shape[0]
-    s = [scale * sum(jnp.einsum("bqd,bkd->bqk", q[i], k[m - i])
+    s = [scale * sum(_einsum("bqd,bkd->bqk", q[i], k[m - i])
                      for i in range(m + 1)) for m in range(n1)]
     shift = jnp.max(s[0], axis=-1, keepdims=True)
     e = [jnp.exp(s[0] - shift)]
@@ -108,7 +112,7 @@ def jet_flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     inf/NaN enters even under differentiation.
     """
     n1 = q.shape[0]
-    s = [scale * sum(jnp.einsum("bhqd,bhkd->bhqk", q[i], k[m - i])
+    s = [scale * sum(_einsum("bhqd,bhkd->bhqk", q[i], k[m - i])
                      for i in range(m + 1)) for m in range(n1)]
     if mask is not None:
         s[0] = jnp.where(mask, s[0], jnp.asarray(-1e30, s[0].dtype))
@@ -121,9 +125,9 @@ def jet_flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     for m in range(1, n1):
         p.append((e[m] - sum(tot[j] * p[m - j] for j in range(1, m + 1)))
                  / tot[0])
-    o = [sum(jnp.einsum("bhqk,bhkd->bhqd", p[i], v[m - i])
+    o = [sum(_einsum("bhqk,bhkd->bhqd", p[i], v[m - i])
              for i in range(m + 1)) for m in range(n1)]
-    return jnp.stack([jnp.einsum("bhqd,hdo->bqo", om, wo) for om in o])
+    return jnp.stack([_einsum("bhqd,hdo->bqo", om, wo) for om in o])
 
 
 def jet_rms_norm_ref(coeffs: jnp.ndarray, gamma: jnp.ndarray,

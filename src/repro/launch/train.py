@@ -28,6 +28,7 @@ from repro.models import init_model, train_loss
 from repro.models.transformer import Knobs
 from repro.optim import adam_init, adam_update
 from repro.runtime import Trainer, TrainerConfig
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -44,6 +45,7 @@ def main() -> None:
     ap.add_argument("--ntp-order", type=int, default=0,
                     help="add an order-n jet smoothness regularizer (dense archs)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

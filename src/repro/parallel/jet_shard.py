@@ -25,7 +25,7 @@ multi-device story exact, not approximate:
 
 Everything here composes with both engine impls: the Pallas kernels run
 per-device inside ``shard_map`` exactly as they do single-device (the
-kernel never sees the mesh).  ``check_rep=False`` throughout: the fused
+kernel never sees the mesh).  ``check_vma=False`` throughout: the fused
 kernels are ``custom_vjp`` ops, which the replication checker cannot see
 through.
 """
@@ -37,7 +37,7 @@ from typing import Any, Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engines import DerivativeEngine
@@ -48,16 +48,28 @@ from .compression import compressed_psum_tree, topk_psum_tree
 DATA_AXIS = "data"
 
 
+def auto_axes(mesh: jax.sharding.Mesh) -> jax.sharding.Mesh:
+    """The same devices and axis names with every axis ``AxisType.Auto``.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, whose arrays carry their
+    sharding in their type; the engines reshape ``shard_map`` outputs, which
+    such types refuse.  The jet paths here shard only through ``shard_map``
+    and leave everything else to the compiler."""
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names,
+                             axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 def resolve_mesh(mesh=None, data_parallel: int = 0,
                  axis: str = DATA_AXIS) -> Optional[jax.sharding.Mesh]:
     """The one knob -> mesh policy: an explicit mesh wins (it must carry the
     data axis), otherwise ``data_parallel=N`` builds a 1-D ``(N,)`` mesh over
-    the first N local devices, and 0/None means single-device (no mesh)."""
+    the first N local devices, and 0/None means single-device (no mesh).
+    Either way the mesh's axes come back ``Auto`` (:func:`auto_axes`)."""
     if mesh is not None:
         if axis not in mesh.shape:
             raise ValueError(f"mesh {mesh!r} has no {axis!r} axis "
                              f"(axes: {tuple(mesh.shape)})")
-        return mesh
+        return auto_axes(mesh)
     if not data_parallel:
         return None
     n = int(data_parallel)
@@ -68,7 +80,7 @@ def resolve_mesh(mesh=None, data_parallel: int = 0,
             f"data_parallel={n} exceeds the {jax.device_count()} visible "
             f"device(s); set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={n} before importing jax, or lower the knob")
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def pad_rows(x: jnp.ndarray, multiple: int) -> Tuple[jnp.ndarray, int]:
@@ -115,6 +127,7 @@ class ShardedEngine(DerivativeEngine):
         if self.axis not in self.mesh.shape:
             raise ValueError(f"mesh has no {self.axis!r} axis "
                              f"(axes: {tuple(self.mesh.shape)})")
+        object.__setattr__(self, "mesh", auto_axes(self.mesh))
 
     @property
     def n_shards(self) -> int:
@@ -132,11 +145,10 @@ class ShardedEngine(DerivativeEngine):
         vp, _ = pad_rows(tangent, self.n_shards)
         inner, axis = self.inner, self.axis
 
-        f = shard_map(lambda p, xs, vs: inner.derivs(net, p, xs, order, vs),
-                      mesh=self.mesh,
-                      in_specs=(P(), P(axis), P(axis)),
-                      out_specs=P(None, axis, None),
-                      check_rep=False)
+        f = jax.shard_map(
+            lambda p, xs, vs: inner.derivs(net, p, xs, order, vs),
+            mesh=self.mesh, in_specs=(P(), P(axis), P(axis)),
+            out_specs=P(None, axis, None), check_vma=False)
         return f(params, xp, vp)[:, :n]
 
     def _batched_directional(self, net: Network, params, x: jnp.ndarray,
@@ -144,12 +156,12 @@ class ShardedEngine(DerivativeEngine):
         out = super()._batched_directional(net, params, x, dirs, order)
         # Replicate before grid/cross assembly.  ``derivs`` leaves its output
         # sharded over the tiled (direction x point) batch axis, so the
-        # polarization tensordot in ``cross`` would reduce over a
-        # device-sharded direction axis -- a cross-device accumulation whose
-        # summation order differs from the single-device launch (a 1-ULP
-        # f32 diff on 16-term order-4 polarizations).  The all-gather is
-        # pure data movement: every value stays bitwise identical, and the
-        # reduction then runs with single-device ordering.
+        # polarization sum in ``cross`` would reduce over a device-sharded
+        # direction axis -- a cross-device accumulation whose summation
+        # order differs from the single-device launch (a 1-ULP f32 diff on
+        # 16-term order-4 polarizations).  The all-gather is pure data
+        # movement: every value stays bitwise identical, and the sum then
+        # runs in the single-device order.
         return jax.device_put(
             out, jax.sharding.NamedSharding(self.mesh, P()))
 
@@ -237,10 +249,10 @@ def build_sharded_train_step(loss_fn: Callable, mesh: jax.sharding.Mesh, *,
         params, opt_state = adam_update(grads, opt_state, params, adam_lr)
         return params, opt_state, (loss, aux), new_err
 
-    sharded = shard_map(local_step, mesh=mesh,
-                        in_specs=(P(), P(), P(axis), P(axis)),
-                        out_specs=(P(), P(), P(), P(axis)),
-                        check_rep=False)
+    sharded = jax.shard_map(local_step, mesh=mesh,
+                            in_specs=(P(), P(), P(axis), P(axis)),
+                            out_specs=(P(), P(), P(), P(axis)),
+                            check_vma=False)
 
     @jax.jit
     def step(params, opt_state, pts, err):

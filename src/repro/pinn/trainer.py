@@ -11,12 +11,13 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.engines import DerivativeEngine
+from repro.core.jet import float_dtype
 from repro.core.network import Network, make_network
 from repro.core.ntp import MLPParams, init_mlp, num_params
 from repro.data.collocation import (boundary_grid, eval_grid, resample,
@@ -72,7 +73,7 @@ def profile_lambda_from_history(res: "PINNResult") -> float:
 
 
 def train(cfg: PINNRunConfig) -> PINNResult:
-    dtype = jnp.float64
+    dtype = float_dtype()
     key = jax.random.PRNGKey(cfg.seed)
     k_init, k_pts = jax.random.split(key)
     params = init_mlp(k_init, 1, cfg.width, cfg.depth, 1, dtype=dtype)
@@ -210,6 +211,10 @@ class OperatorResult:
     lbfgs_time_s: float
     n_params: int
     net: Optional[Network] = None
+    # the jitted Adam step the run used, kept so callers can inspect its
+    # compiled program: (params, opt_state, pts) -> (params, opt_state,
+    # loss) single-device, ShardedTrainStep.step under a mesh
+    train_step: Optional[Callable] = None
 
 
 def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
@@ -217,7 +222,7 @@ def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
     operator's exact solution supplies boundary/initial data and the final
     accuracy oracle."""
     op = get_operator(cfg.op)
-    dtype = jnp.float64
+    dtype = float_dtype()
     key = jax.random.PRNGKey(cfg.seed)
     k_init, k_pts = jax.random.split(key)
     net = make_network(cfg.network, d_in=op.d_in, d_out=op.d_out,
@@ -245,6 +250,7 @@ def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
                                                     has_aux=True)(p, pts)
             p, state = adam_update(grads, state, p, cfg.adam_lr)
             return p, state, loss
+        train_step = adam_step
     else:
         # one shard_map program per step: local loss+grad on each device's
         # collocation shard, psum (optionally compressed) of the grads, and
@@ -257,6 +263,7 @@ def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
             loss_fn, mesh, adam_lr=cfg.adam_lr,
             compression=cfg.grad_compression)
         ef_err = built.init_err(params)
+        train_step = built.step
 
         def adam_step(p, state, pts):
             nonlocal ef_err
@@ -307,4 +314,5 @@ def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
     return OperatorResult(params=params, op_name=op.name,
                           loss_history=loss_hist, l2_error=l2,
                           adam_time_s=adam_time, lbfgs_time_s=lbfgs_time,
-                          n_params=num_params(params), net=net)
+                          n_params=num_params(params), net=net,
+                          train_step=train_step)

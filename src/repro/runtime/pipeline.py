@@ -26,7 +26,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -70,9 +69,9 @@ def gpipe(stage_fn: Callable, mesh: jax.sharding.Mesh, *, axis: str = "stage"):
             return jax.lax.psum(done * mask, axis)
 
         specs_p = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-        return shard_map(inner, mesh=mesh,
-                         in_specs=(specs_p, P()),
-                         out_specs=P(), check_rep=False)(stage_params, xs)
+        return jax.shard_map(inner, mesh=mesh,
+                             in_specs=(specs_p, P()),
+                             out_specs=P(), check_vma=False)(stage_params, xs)
 
     return pipelined
 

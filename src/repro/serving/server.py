@@ -41,7 +41,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engines import DerivativeEngine, EngineSpec
+from repro.core.jet import float_dtype
 from repro.core.network import Network
+from repro.parallel.jet_shard import auto_axes
 from repro.runtime.metrics import LatencyStats
 
 from .bucketing import DEFAULT_BUCKETS, pad_fraction, pad_to, pick_bucket
@@ -136,6 +138,7 @@ class DerivativeServer:
             raise ValueError("need at least one bucket size")
         self.mesh = mesh
         if mesh is not None:
+            self.mesh = auto_axes(mesh)
             if "data" not in mesh.shape:
                 raise ValueError(f"serving mesh needs a 'data' axis, got "
                                  f"axes {tuple(mesh.shape)}")
@@ -176,11 +179,12 @@ class DerivativeServer:
     # ------------------------------------------------------------ lifecycle
     @classmethod
     def from_checkpoint(cls, directory: str, net: Network, *,
-                        step: Optional[int] = None, dtype=jnp.float64,
+                        step: Optional[int] = None, dtype=None,
                         engine="ntp", init_key: Optional[jax.Array] = None,
                         **kwargs) -> "DerivativeServer":
         """Restore ``net``'s parameters from a ``ckpt.CheckpointManager``
-        directory (latest step by default) and serve them."""
+        directory (latest step by default) and serve them.  ``dtype``
+        defaults to :func:`repro.core.jet.float_dtype`."""
         from repro.ckpt import CheckpointManager
 
         mgr = CheckpointManager(directory)
@@ -190,7 +194,8 @@ class DerivativeServer:
                 raise FileNotFoundError(
                     f"no checkpoints under {directory!r}")
         like = net.init(init_key if init_key is not None
-                        else jax.random.PRNGKey(0), dtype=dtype)
+                        else jax.random.PRNGKey(0),
+                        dtype=float_dtype() if dtype is None else dtype)
         params = mgr.restore(step, like)
         return cls(net, params, engine, **kwargs)
 
@@ -404,13 +409,12 @@ class DerivativeServer:
             # replicated, the padded batch split over the data axis (bucket
             # divisibility was validated at construction, and zero pad rows
             # are batch-independent, so sharding never changes live bits)
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             batch_axis = 2 if group.kind == "grid" else 0
             out_spec = P(*([None] * batch_axis + ["data"]))
-            compute = shard_map(compute, mesh=self.mesh,
-                                in_specs=(P(), P("data")),
-                                out_specs=out_spec, check_rep=False)
+            compute = jax.shard_map(compute, mesh=self.mesh,
+                                    in_specs=(P(), P("data")),
+                                    out_specs=out_spec, check_vma=False)
 
         donate = (1,) if self._donate else ()
         x_spec = jax.ShapeDtypeStruct((bucket, net.d_in),

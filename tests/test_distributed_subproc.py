@@ -44,7 +44,7 @@ def test_small_mesh_train_step_runs():
     """A real (executed, not just compiled) sharded train step on a 4x2 mesh."""
     print(run_py("""
         import jax, jax.numpy as jnp
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.configs import get_arch
         from repro.configs.base import ShapeCfg
         from repro.launch.sharding import build_train_step
@@ -52,7 +52,8 @@ def test_small_mesh_train_step_runs():
         from repro.models import init_model
         from repro.optim import adam_init
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg = get_arch("qwen3-0.6b").reduced()
         shape = ShapeCfg("t", 32, 8, "train")
         built = build_train_step(cfg, mesh, shape, fsdp=False)
@@ -115,15 +116,14 @@ def test_compressed_psum_matches_fp32():
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from functools import partial
-        from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.parallel.compression import compressed_psum_tree, ef_init
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = jax.make_mesh((8,), ("pod",), axis_types=(AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 2.0
         err = jnp.zeros((8, 64), jnp.bfloat16)
 
-        @partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
+        @partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
                  out_specs=(P("pod"), P("pod")))
         def red(g, e):
             out, e2 = compressed_psum_tree({"g": g}, {"g": e}, "pod")

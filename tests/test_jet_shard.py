@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.engines import NTPEngine
@@ -148,10 +147,10 @@ def _reduce_loop(comp, g, err_dtype, steps):
         out, e2 = comp({"g": gg}, {"g": ee}, DATA_AXIS)
         return out["g"], e2["g"]
 
-    red = jax.jit(shard_map(body, mesh=mesh,
-                            in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
-                            out_specs=(P(DATA_AXIS), P(DATA_AXIS)),
-                            check_rep=False))
+    red = jax.jit(jax.shard_map(body, mesh=mesh,
+                                in_specs=(P(DATA_AXIS), P(DATA_AXIS)),
+                                out_specs=(P(DATA_AXIS), P(DATA_AXIS)),
+                                check_vma=False))
     err = jnp.zeros(g.shape, err_dtype)
     acc = jnp.zeros(g.shape[1:])
     for _ in range(steps):
@@ -354,13 +353,12 @@ def test_error_feedback_convergence_on_real_mesh(devices):
     print(run_py(f"""
         import jax, jax.numpy as jnp
         from functools import partial
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import PartitionSpec as P
+        from jax.sharding import AxisType, PartitionSpec as P
         from repro.parallel.compression import (compressed_psum_tree,
                                                 topk_psum_tree)
 
         D = {devices}
-        mesh = jax.make_mesh((D,), ("data",))
+        mesh = jax.make_mesh((D,), ("data",), axis_types=(AxisType.Auto,))
         g = jax.random.normal(jax.random.PRNGKey(0), (D, 128)) * 3.0
         true = jnp.sum(g, 0)
         cases = (("int8", compressed_psum_tree, 0.01),
@@ -368,11 +366,11 @@ def test_error_feedback_convergence_on_real_mesh(devices):
                   lambda gg, ee, ax: topk_psum_tree(gg, ee, ax, k_frac=0.2),
                   0.05))
         for name, comp, tol in cases:
-            red = shard_map(
+            red = jax.shard_map(
                 lambda gg, ee, _c=comp: tuple(
                     t["g"] for t in _c({{"g": gg}}, {{"g": ee}}, "data")),
                 mesh=mesh, in_specs=(P("data"), P("data")),
-                out_specs=(P("data"), P("data")), check_rep=False)
+                out_specs=(P("data"), P("data")), check_vma=False)
             err = jnp.zeros((D, 128), jnp.float32)
             acc = jnp.zeros((128,))
             K = 50

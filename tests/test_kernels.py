@@ -85,6 +85,19 @@ def test_sigmoid_kernel_path():
     np.testing.assert_allclose(got, want, rtol=5e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("activation", ["softplus", "gelu"])
+def test_kernel_dispatch_refuses_activation_without_table(activation):
+    """An activation without a Taylor table is an error at the kernel
+    dispatch, not a silent detour through the reference; modules compose
+    it through the jet algebra after the kernel's linear part instead."""
+    c = jnp.ones((3, 4, 8), jnp.float32)
+    w, b = jnp.ones((8, 8), jnp.float32), jnp.zeros((8,), jnp.float32)
+    with pytest.raises(ValueError, match="no Pallas Taylor table"):
+        ops.act_jet(c, activation)
+    with pytest.raises(ValueError, match="no Pallas Taylor table"):
+        ops.jet_dense(c, w, b, activation)
+
+
 def test_sin_kernel_path():
     """The SIREN / Fourier-trunk activation runs in-kernel (cyclic
     sigma^(m)(a) = sin(a + m pi/2) stack), not via the reference fallback."""

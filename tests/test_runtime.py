@@ -151,3 +151,27 @@ def test_compressed_psum_multidevice_if_available(tmp_path):
     """Correctness of the compressed psum under shard_map (skips with 1 dev)."""
     if jax.device_count() < 2:
         pytest.skip("single-device container; covered by test_dryrun_subproc")
+
+
+def test_compile_cache_env_dir_wins_else_fixed_checkout_dir(monkeypatch,
+                                                           tmp_path):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache directory; without
+    it the cache sits at a fixed, git-ignored path inside the checkout."""
+    from pathlib import Path
+
+    from repro.runtime import compile_cache as cc
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(cc.ENV_VAR, str(tmp_path))
+        assert cc.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+
+        monkeypatch.delenv(cc.ENV_VAR)
+        assert cc.enable_compile_cache() == str(cc.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.DEFAULT_DIR)
+        root = Path(__file__).resolve().parents[1]
+        assert cc.DEFAULT_DIR == root / ".jax_cache"
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

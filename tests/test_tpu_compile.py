@@ -1,0 +1,126 @@
+"""The Pallas kernels compile for a TPU v5e, with no chip attached.
+
+Everywhere else the suite runs the kernels in interpret mode on the CPU,
+which cannot see what Mosaic (the TPU kernel compiler) refuses: scatters,
+matmuls with more than one batch axis, tiles beyond VMEM, 64-bit types.
+Here each kernel of the main path, and one jitted ``ntp/pallas`` grid, is
+compiled for a described ``v5e:2x2`` topology at the pinn-pde widths (32
+wide, 2 heads x 16) and at a lane-aligned width (128), in f32, at orders 2
+and 4, and the compiled program must call the kernel (``tpu_custom_call``).
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU compiler library at a time, and every test worker
+imports this file.  Keep these tests in this one file, so that one worker
+loads it.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.engines import NTPEngine
+from repro.core.network import DenseMLP
+from repro.kernels import ops
+from repro.kernels.jet_attention import (jet_flash_attention_pallas,
+                                         jet_rms_norm_pallas)
+from repro.kernels.jet_dense import jet_dense_pallas
+from repro.kernels.tanh_jet import act_jet_pallas
+
+BATCH = 1024            # collocation rows (pinn-pde trains on 1024 points)
+HEADS = 2
+TOKENS = 2              # coordinate tokens: d_in of the (t, x) operators
+WIDTHS = (32, 128)      # pinn-pde width, and a lane-aligned one
+ORDERS = (2, 4)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            return topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:        # noqa: BLE001 -- any failure skips
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one; keep the cache out of it
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def compile_for_chip(fn, *shapes, sharding):
+    """Compile ``fn`` in f32 (x64 off, as on the chip) for the described
+    chip; returns the compiled program's text."""
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=sharding)
+            for s in shapes]
+    with jax.enable_x64(False):
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("order", ORDERS)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_jet_dense_compiles(one_chip, width, order):
+    text = compile_for_chip(
+        lambda c, w, b: jet_dense_pallas(c, w, b, "tanh", interpret=False),
+        (order + 1, BATCH, width), (width, width), (width,),
+        sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("order", ORDERS)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_act_jet_compiles(one_chip, width, order):
+    text = compile_for_chip(
+        lambda c: act_jet_pallas(c, "tanh", interpret=False),
+        (order + 1, BATCH, width), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("order", ORDERS)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_jet_rms_norm_compiles(one_chip, width, order):
+    text = compile_for_chip(
+        lambda c, g: jet_rms_norm_pallas(c, g, interpret=False),
+        (order + 1, BATCH * TOKENS, width), (width,), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("order", ORDERS)
+@pytest.mark.parametrize("width", WIDTHS)
+def test_jet_flash_attention_compiles(one_chip, width, order):
+    qkv = (order + 1, BATCH, HEADS, TOKENS, width // HEADS)
+    text = compile_for_chip(
+        lambda q, k, v, wo: jet_flash_attention_pallas(
+            q, k, v, wo, 0.25, interpret=False),
+        qkv, qkv, qkv, (HEADS, width // HEADS, width), sharding=one_chip)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("order", ORDERS)
+def test_ntp_pallas_grid_compiles(one_chip, order, monkeypatch):
+    """The whole order-n derivative table of the pinn-pde dense model, as
+    the engine dispatches it: the kernels must be chosen compiled, not
+    interpreted (the dispatch asks ``ops._on_tpu``, which sees the CPU)."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    net = DenseMLP(d_in=2, width=WIDTHS[0], depth=3, d_out=1)
+    engine = NTPEngine("pallas")
+    with jax.enable_x64(False):
+        pshape = jax.eval_shape(
+            lambda: net.init(jax.random.PRNGKey(0), jnp.float32))
+        params = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), pshape)
+        x = jax.ShapeDtypeStruct((BATCH, net.d_in), jnp.float32,
+                                 sharding=one_chip)
+        text = jax.jit(lambda p, xx: engine.grid(net, p, xx, order)) \
+            .lower(params, x).compile().as_text()
+    # one fused dense+activation launch per layer
+    assert text.count("tpu_custom_call") >= net.depth + 1
