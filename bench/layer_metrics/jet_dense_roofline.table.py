@@ -1,0 +1,18 @@
+"""``jet_dense``'s share of its roofline in the table cell: the least time
+its calls of the traced window could take (``bench/work``) over the device
+time of its events."""
+
+from bench import work
+
+
+def read(ctx):
+    trace = ctx.get("trace")
+    if trace is None or not ctx.get("calls"):
+        return None
+    seconds = trace.kernel_s("jet_dense")
+    if not seconds:
+        return None
+    least, _ = work.roofline_seconds(ctx["kernel_calls_per_call"],
+                                     ctx["peaks"]["flops_per_s"],
+                                     ctx["peaks"]["hbm_bytes_per_s"])
+    return 100.0 * least * ctx["calls"] / seconds
