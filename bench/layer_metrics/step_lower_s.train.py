@@ -1,0 +1,13 @@
+"""Seconds of lowering the program's jitted train step to an MLIR module
+in this process, from the program's own compile counters: set-up work that
+no compile cache saves."""
+
+
+def read(ctx):
+    try:
+        from repro.pinn.trainer import TRAIN_STEP_NAME
+        from repro.runtime.metrics import snapshot
+    except ImportError:              # a program without the counters
+        return None
+    count, seconds = snapshot()["lower"].get(TRAIN_STEP_NAME, (0, 0.0))
+    return seconds if count else None

@@ -48,7 +48,7 @@ def main() -> None:
     enable_compile_cache()
 
     from . import (burgers_e2e, fwd_bwd, memory_scaling, operators_bench,
-                   partition_growth, ratio_grid, roofline, serving_bench)
+                   partition_growth, ratio_grid, serving_bench)
 
     mode = "smoke" if args.smoke else ("full" if args.full else "fast")
     # one entry per suite: (runner, {mode: kwargs}) -- a new suite added here
@@ -86,7 +86,6 @@ def main() -> None:
             "smoke": dict(adam_steps=4, lbfgs_steps=2),
             "fast": dict(adam_steps=40, lbfgs_steps=8),
             "full": dict(adam_steps=200, lbfgs_steps=40)}),
-        "roofline": (roofline.run, {"smoke": {}, "fast": {}, "full": {}}),
     }
     if args.only and args.only not in registry:
         ap.error(f"unknown suite {args.only!r}; known: "

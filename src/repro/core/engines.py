@@ -56,6 +56,8 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.metrics import scope
+
 from . import jet as J
 from .network import Network
 
@@ -149,17 +151,21 @@ class DerivativeEngine:
         with the direction axis folded into the batch -- one large forward
         instead of a vmap over per-direction passes."""
         n_dirs, batch = dirs.shape[0], x.shape[0]
-        xt = jnp.tile(x, (n_dirs, 1))
-        vt = jnp.repeat(dirs, batch, axis=0)
+        with scope("ntp.fold"):
+            xt = jnp.tile(x, (n_dirs, 1))
+            vt = jnp.repeat(dirs, batch, axis=0)
         d = self.derivs(net, params, xt, order, vt)
-        return jnp.moveaxis(d.reshape((order + 1, n_dirs, batch, -1)), 1, 0)
+        with scope("ntp.fold"):
+            return jnp.moveaxis(d.reshape((order + 1, n_dirs, batch, -1)),
+                                1, 0)
 
     def grid(self, net: Network, params, x: jnp.ndarray,
              order: int) -> jnp.ndarray:
         """Pure derivatives along every coordinate axis:
         (d_in, order+1, N, d_out)."""
-        eye = jnp.eye(x.shape[-1], dtype=x.dtype)
-        return self._batched_directional(net, params, x, eye, order)
+        with scope("ntp.grid"):
+            eye = jnp.eye(x.shape[-1], dtype=x.dtype)
+            return self._batched_directional(net, params, x, eye, order)
 
     def cross(self, net: Network, params, x: jnp.ndarray,
               axes: Sequence[int]) -> jnp.ndarray:
@@ -177,17 +183,20 @@ class DerivativeEngine:
         if any(a < 0 or a >= d for a in axes):
             raise ValueError(f"axes {tuple(axes)} out of range for d_in={d}")
         signs = list(itertools.product((1.0, -1.0), repeat=m))
-        basis = jnp.eye(d, dtype=x.dtype)[jnp.asarray(axes)]   # (m, d)
-        dirs = jnp.asarray(signs, x.dtype) @ basis              # (2^m, d)
-        derivs = self._batched_directional(net, params, x, dirs, m)
-        # the +-1 weights are static: add the signed terms in a fixed order
-        # (not a matmul), so every launch -- one device or a mesh -- sums
-        # the same way
-        top = None                                              # (N, d_out)
-        for i, eps in enumerate(signs):
-            term = derivs[i, m] if math.prod(eps) > 0 else -derivs[i, m]
-            top = term if top is None else top + term
-        return top / (2.0 ** m * math.factorial(m))
+        with scope("ntp.cross"):
+            basis = jnp.eye(d, dtype=x.dtype)[jnp.asarray(axes)]   # (m, d)
+            dirs = jnp.asarray(signs, x.dtype) @ basis              # (2^m, d)
+            derivs = self._batched_directional(net, params, x, dirs, m)
+            # the +-1 weights are static: add the signed terms in a fixed
+            # order (not a matmul), so every launch -- one device or a mesh
+            # -- sums the same way
+            with scope("ntp.polarize"):
+                top = None                                      # (N, d_out)
+                for i, eps in enumerate(signs):
+                    term = derivs[i, m] if math.prod(eps) > 0 \
+                        else -derivs[i, m]
+                    top = term if top is None else top + term
+                return top / (2.0 ** m * math.factorial(m))
 
     # -- spec parsing -------------------------------------------------------
 

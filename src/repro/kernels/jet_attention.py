@@ -128,6 +128,7 @@ def jet_attention_scores_pallas(q: jnp.ndarray, k: jnp.ndarray, scale: float,
         out_specs=pl.BlockSpec((n1, bb, t, t), lambda i: (0, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n1, qp.shape[1], t, t), q.dtype),
         interpret=interpret,
+        name="jet_attention_scores",
     )(qp, kp)
     return out[:, :bsz]
 
@@ -349,6 +350,7 @@ def jet_flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             pltpu.VMEM((h, n1, bb, bq, d), acc_t),
         ],
         interpret=interpret,
+        name="jet_flash_attention",
     )(qp, kp, vp, wo)
     return out[:, :bsz, :t]
 
@@ -418,5 +420,6 @@ def jet_rms_norm_pallas(coeffs: jnp.ndarray, gamma: jnp.ndarray,
         out_specs=pl.BlockSpec((n1, bb, w), lambda i: (0, i, 0)),
         out_shape=jax.ShapeDtypeStruct(xp.shape, coeffs.dtype),
         interpret=interpret,
+        name="jet_rms_norm",
     )(xp, gamma.reshape(1, -1))
     return out[:, :bsz]

@@ -97,5 +97,6 @@ def jet_dense_pallas(coeffs: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                                                      jnp.float32))],
         compiler_params=compiler_params,
         interpret=interpret,
+        name="jet_dense",
     )(y, wp, bp)
     return out[:, :bsz, :dout]

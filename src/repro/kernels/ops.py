@@ -37,6 +37,8 @@ from typing import Mapping
 import jax
 import jax.numpy as jnp
 
+from repro.runtime.metrics import scope
+
 from . import ref
 from .jet_attention import (jet_attention_scores_pallas,
                             jet_flash_attention_pallas, jet_rms_norm_pallas)
@@ -113,7 +115,9 @@ def _fold_batch(coeffs: jnp.ndarray, keep: int = 1) -> tuple[jnp.ndarray, tuple]
 #  - the recompute is one extra fused-layer-equivalent of FLOPs, the same
 #    trade remat makes for ordinary transformer layers on TPU.
 # The custom_vjp cores are 3-D ((n+1, B, D)); the public wrappers fold any
-# extra leading batch axes around them.
+# extra leading batch axes around them.  Each backward runs under a
+# ``kernel.<kernel>.bwd`` scope, so the recompute's device operations carry
+# that name in their ``op_name``.
 # ---------------------------------------------------------------------------
 
 def _act_jet_impl(coeffs: jnp.ndarray, activation: str) -> jnp.ndarray:
@@ -130,6 +134,7 @@ def _act_jet_fwd(coeffs, activation):
     return _act_jet_impl(coeffs, activation), coeffs
 
 
+@scope("kernel.act_jet.bwd")
 def _act_jet_bwd(activation, coeffs, g):
     _, vjp = jax.vjp(lambda c: ref.act_jet_ref(c, activation), coeffs)
     return vjp(g)
@@ -161,6 +166,7 @@ def _jet_dense_fwd(coeffs, w, b, activation):
     return _jet_dense_impl(coeffs, w, b, activation), (coeffs, w, b)
 
 
+@scope("kernel.jet_dense.bwd")
 def _jet_dense_bwd(activation, res, g):
     coeffs, w, b = res
     _, vjp = jax.vjp(lambda c, ww, bb: ref.jet_dense_ref(c, ww, bb, activation),
@@ -201,6 +207,7 @@ def _attention_scores_fwd(q, k, scale):
     return _attention_scores_impl(q, k, scale), (q, k)
 
 
+@scope("kernel.attention_scores.bwd")
 def _attention_scores_bwd(scale, res, g):
     q, k = res
     _, vjp = jax.vjp(
@@ -249,6 +256,7 @@ def _flash_attention_fwd(q, k, v, wo, scale, mask):
     return _flash_attention_impl(q, k, v, wo, scale, mask), (q, k, v, wo)
 
 
+@scope("kernel.flash_attention.bwd")
 def _flash_attention_bwd(scale, mask, res, g):
     from repro.core.modules import attention_mask
     q, k, v, wo = res
@@ -304,6 +312,7 @@ def _rms_norm_fwd(coeffs, gamma, eps):
     return _rms_norm_impl(coeffs, gamma, eps), (coeffs, gamma)
 
 
+@scope("kernel.rms_norm.bwd")
 def _rms_norm_bwd(eps, res, g):
     coeffs, gamma = res
     _, vjp = jax.vjp(lambda c, gg: ref.jet_rms_norm_ref(c, gg, eps),

@@ -100,5 +100,6 @@ def act_jet_pallas(coeffs: jnp.ndarray, activation: str = "tanh",
         out_specs=pl.BlockSpec((n1, bb, bw), lambda i, j: (0, i, j)),
         out_shape=jax.ShapeDtypeStruct(padded.shape, coeffs.dtype),
         interpret=interpret,
+        name="act_jet",
     )(padded)
     return out[:, :b, :w]

@@ -26,6 +26,7 @@ from repro.core import jet as J
 from repro.core.engines import DerivativeEngine
 from repro.core.network import Network
 from repro.core.ntp import MLPParams, mlp_apply
+from repro.runtime.metrics import scope
 
 from .burgers import exact_profile, residual_derivs_autodiff, residual_jet
 from .operators import Operator, build_table, get_operator
@@ -69,17 +70,21 @@ def pinn_loss(params, *, op: Union[Operator, str], pts: jnp.ndarray,
     if mesh is not None:
         from repro.parallel.jet_shard import ShardedEngine
         eng = ShardedEngine(eng, mesh)
-    r = op.residual(pts, build_table(net, params, eng, op, pts))
-    l_res = jnp.mean(r ** 2)
-    ub = net.apply(params, bc_pts)                       # (Nb, d_out)
-    bv = jnp.asarray(bc_vals)
-    if bv.ndim == 1:
-        bv = bv[:, None]
-    if bv.shape != ub.shape:
-        raise ValueError(
-            f"bc_vals shape {bv.shape} does not match the network's boundary "
-            f"output {ub.shape}; systems need one column per component")
-    l_bc = jnp.mean((ub - bv) ** 2)
+    table = build_table(net, params, eng, op, pts)
+    with scope("pinn.residual"):
+        r = op.residual(pts, table)
+        l_res = jnp.mean(r ** 2)
+    with scope("pinn.boundary"):
+        ub = net.apply(params, bc_pts)                   # (Nb, d_out)
+        bv = jnp.asarray(bc_vals)
+        if bv.ndim == 1:
+            bv = bv[:, None]
+        if bv.shape != ub.shape:
+            raise ValueError(
+                f"bc_vals shape {bv.shape} does not match the network's "
+                f"boundary output {ub.shape}; systems need one column per "
+                f"component")
+        l_bc = jnp.mean((ub - bv) ** 2)
     loss = weights.residual * l_res + weights.bc * l_bc
     return loss, {"residual": l_res, "bc": l_bc}
 

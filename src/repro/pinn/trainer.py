@@ -25,6 +25,7 @@ from repro.data.collocation import (boundary_grid, eval_grid, resample,
 from repro.optim import adam_init, adam_update, lbfgs
 from repro.parallel.jet_shard import (ShardedEngine, build_sharded_train_step,
                                       resolve_mesh)
+from repro.runtime.metrics import scope
 
 from .burgers import lambda_window, profile_lambda, smoothness_order
 from .losses import LossWeights, bc_targets, burgers_pinn_loss, pinn_loss
@@ -157,6 +158,15 @@ def _lam_of(lam_raw, window):
 # generic operator training (method of manufactured solutions)
 # ---------------------------------------------------------------------------
 
+# the name of train_operator's jitted single-device step, as JAX's compile
+# events report it (repro.runtime.metrics counts its tracing, lowering and
+# compiling under this name); no other function of the program bears it
+TRAIN_STEP_NAME = "pinn_train_step"
+# the scope of train_operator's set-up: building the step, and the closing
+# accuracy check with its own compile
+SETUP_SCOPE = "pinn.setup"
+
+
 @dataclass
 class OperatorRunConfig:
     """Training config for any registered differential operator.
@@ -220,55 +230,59 @@ class OperatorResult:
 def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
     """Adam (+ optional L-BFGS) on the generic operator objective; the
     operator's exact solution supplies boundary/initial data and the final
-    accuracy oracle."""
-    op = get_operator(cfg.op)
-    dtype = float_dtype()
-    key = jax.random.PRNGKey(cfg.seed)
-    k_init, k_pts = jax.random.split(key)
-    net = make_network(cfg.network, d_in=op.d_in, d_out=op.d_out,
-                       width=cfg.width, depth=cfg.depth,
-                       activation=cfg.activation, **cfg.net_kwargs)
-    engine = DerivativeEngine.from_spec(cfg.engine)
-    params = net.init(k_init, dtype=dtype)
+    accuracy oracle.  Building the step and the final accuracy check run
+    under the :data:`SETUP_SCOPE` scope (``repro.runtime.metrics``)."""
+    with scope(SETUP_SCOPE):
+        op = get_operator(cfg.op)
+        dtype = float_dtype()
+        key = jax.random.PRNGKey(cfg.seed)
+        k_init, k_pts = jax.random.split(key)
+        net = make_network(cfg.network, d_in=op.d_in, d_out=op.d_out,
+                           width=cfg.width, depth=cfg.depth,
+                           activation=cfg.activation, **cfg.net_kwargs)
+        engine = DerivativeEngine.from_spec(cfg.engine)
+        params = net.init(k_init, dtype=dtype)
 
-    bc_pts = boundary_grid(op.domain, cfg.n_bc, dtype)
-    bc_vals = exact_values(op, bc_pts, dtype)
+        bc_pts = boundary_grid(op.domain, cfg.n_bc, dtype)
+        bc_vals = exact_values(op, bc_pts, dtype)
 
-    def make_loss(eng):
-        def loss_fn(p, pts):
-            return pinn_loss(p, op=op, pts=pts, bc_pts=bc_pts,
-                             bc_vals=bc_vals, weights=cfg.weights,
-                             engine=eng, net=net)
-        return loss_fn
+        def make_loss(eng):
+            def loss_fn(p, pts):
+                return pinn_loss(p, op=op, pts=pts, bc_pts=bc_pts,
+                                 bc_vals=bc_vals, weights=cfg.weights,
+                                 engine=eng, net=net)
+            return loss_fn
 
-    loss_fn = make_loss(engine)
-    mesh = resolve_mesh(cfg.mesh, cfg.data_parallel)
-    if mesh is None:
-        @jax.jit
-        def adam_step(p, state, pts):
-            (loss, aux), grads = jax.value_and_grad(loss_fn,
-                                                    has_aux=True)(p, pts)
-            p, state = adam_update(grads, state, p, cfg.adam_lr)
-            return p, state, loss
-        train_step = adam_step
-    else:
-        # one shard_map program per step: local loss+grad on each device's
-        # collocation shard, psum (optionally compressed) of the grads, and
-        # a replicated Adam update -- see repro.parallel.jet_shard
-        if cfg.n_domain % mesh.shape["data"]:
-            raise ValueError(
-                f"n_domain={cfg.n_domain} does not divide the "
-                f"{mesh.shape['data']}-way data axis of the mesh")
-        built = build_sharded_train_step(
-            loss_fn, mesh, adam_lr=cfg.adam_lr,
-            compression=cfg.grad_compression)
-        ef_err = built.init_err(params)
-        train_step = built.step
+        loss_fn = make_loss(engine)
+        mesh = resolve_mesh(cfg.mesh, cfg.data_parallel)
+        if mesh is None:
+            def pinn_train_step(p, state, pts):
+                (loss, aux), grads = jax.value_and_grad(loss_fn,
+                                                        has_aux=True)(p, pts)
+                with scope("optim.adam"):
+                    p, state = adam_update(grads, state, p, cfg.adam_lr)
+                return p, state, loss
+            train_step = adam_step = jax.jit(pinn_train_step)
+        else:
+            # one shard_map program per step: local loss+grad on each
+            # device's collocation shard, psum (optionally compressed) of
+            # the grads, and a replicated Adam update -- see
+            # repro.parallel.jet_shard
+            if cfg.n_domain % mesh.shape["data"]:
+                raise ValueError(
+                    f"n_domain={cfg.n_domain} does not divide the "
+                    f"{mesh.shape['data']}-way data axis of the mesh")
+            built = build_sharded_train_step(
+                loss_fn, mesh, adam_lr=cfg.adam_lr,
+                compression=cfg.grad_compression)
+            ef_err = built.init_err(params)
+            train_step = built.step
 
-        def adam_step(p, state, pts):
-            nonlocal ef_err
-            p, state, (loss, aux), ef_err = built.step(p, state, pts, ef_err)
-            return p, state, loss
+            def adam_step(p, state, pts):
+                nonlocal ef_err
+                p, state, (loss, aux), ef_err = built.step(p, state, pts,
+                                                           ef_err)
+                return p, state, loss
 
     state = adam_init(params)
     pts = sample_box(k_pts, op.domain, cfg.n_domain, dtype)
@@ -306,10 +320,11 @@ def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
         params = res.params
         loss_hist.extend(res.loss_history)
 
-    xe = eval_grid(op.domain, cfg.eval_pts_per_axis, dtype)
-    u_net = net.apply(params, xe)                   # (N, d_out)
-    u_true = exact_values(op, xe, dtype)
-    l2 = float(jnp.sqrt(jnp.mean((u_net - u_true) ** 2)))
+    with scope(SETUP_SCOPE):
+        xe = eval_grid(op.domain, cfg.eval_pts_per_axis, dtype)
+        u_net = net.apply(params, xe)               # (N, d_out)
+        u_true = exact_values(op, xe, dtype)
+        l2 = float(jnp.sqrt(jnp.mean((u_net - u_true) ** 2)))
 
     return OperatorResult(params=params, op_name=op.name,
                           loss_history=loss_hist, l2_error=l2,

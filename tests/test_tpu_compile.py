@@ -7,12 +7,16 @@ Here each kernel of the main path, and one jitted ``ntp/pallas`` grid, is
 compiled for a described ``v5e:2x2`` topology at the pinn-pde widths (32
 wide, 2 heads x 16) and at a lane-aligned width (128), in f32, at orders 2
 and 4, and the compiled program must call the kernel (``tpu_custom_call``).
+A small PINN train step compiled the same way must carry the layer scopes
+(``repro.runtime.metrics.scope``) in its operations' ``op_name``.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library at a time, and every test worker
 imports this file.  Keep these tests in this one file, so that one worker
 loads it.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +30,10 @@ from repro.kernels.jet_attention import (jet_flash_attention_pallas,
                                          jet_rms_norm_pallas)
 from repro.kernels.jet_dense import jet_dense_pallas
 from repro.kernels.tanh_jet import act_jet_pallas
+from repro.optim import adam_init
+from repro.pinn import OperatorRunConfig, train_operator
+from repro.pinn.operators import (Operator, get_operator, operator_names,
+                                  register)
 
 BATCH = 1024            # collocation rows (pinn-pde trains on 1024 points)
 HEADS = 2
@@ -124,3 +132,86 @@ def test_ntp_pallas_grid_compiles(one_chip, order, monkeypatch):
             .lower(params, x).compile().as_text()
     # one fused dense+activation launch per layer
     assert text.count("tpu_custom_call") >= net.depth + 1
+
+
+# the scopes a dense net's train step runs through (``pinn.setup`` is a
+# host span outside the step; the dense kernel fuses the activation, so
+# ``kernel.act_jet.bwd`` is compiled on its own below)
+STEP_SCOPES = ("ntp.grid", "ntp.cross", "ntp.fold", "ntp.polarize",
+               "kernel.jet_dense.bwd", "pinn.residual", "pinn.boundary",
+               "optim.adam")
+SCOPED_OP = "kdv-uxxt"
+COMPILED_OP = re.compile(r"^\s*%(\S+) = .* (fusion|convolution|custom-call)\(")
+
+
+def _kdv_uxxt_residual(x, d):
+    return d(0, 1) + 6.0 * d(0, 0) * d(1, 1) + d(1, 3) + d.mixed(0, 1, 1)
+
+
+def _names(op_name):
+    """The path components of an ``op_name``, transforms unwrapped:
+    ``jit(f)/transpose(jvp(ntp.grid))/mul`` -> {jit, f, transpose, jvp,
+    ntp.grid, mul}."""
+    return set(re.findall(r"[\w.]+", op_name))
+
+
+def _all_names(text):
+    """The path components of every ``op_name`` in a compiled program."""
+    return _names(" ".join(re.findall(r'op_name="([^"]*)"', text)))
+
+
+def _scoped_ops(text):
+    """(instruction name, kind, op_name) of every compiled fusion,
+    convolution and custom call that carries an ``op_name``."""
+    out = []
+    for line in text.splitlines():
+        m, name = COMPILED_OP.match(line), re.search(r'op_name="([^"]*)"', line)
+        if m and name:
+            out.append((m.group(1), m.group(2), name.group(1)))
+    return out
+
+
+def test_train_step_carries_the_layer_scopes(one_chip, monkeypatch):
+    """Dense tanh net (width 8, depth 2), 64 points, ``ntp/pallas``, an
+    order-3 operator with one mixed partial: every scope of the step names
+    some operation (the polarization sum fuses into its neighbours, so it
+    is looked for in every instruction's ``op_name``), at least 95% of the
+    compiled fusions, convolutions and custom calls that carry an
+    ``op_name`` lie under a scope, and the kernel's custom calls keep
+    ``jet_dense`` in their instruction names (what the benchmark's kernel
+    readers sum)."""
+    if SCOPED_OP not in operator_names():
+        kdv = get_operator("kdv")
+        register(Operator(name=SCOPED_OP, d_in=2, order=3,
+                          residual=_kdv_uxxt_residual, exact=kdv.exact,
+                          domain=kdv.domain, mixed=((0, 1, 1),)))
+    with jax.enable_x64(False):
+        res = train_operator(OperatorRunConfig(
+            op=SCOPED_OP, width=8, depth=2, n_domain=64, n_bc=4,
+            adam_steps=0, engine="ntp/pallas", eval_pts_per_axis=2))
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (res.params, adam_init(res.params),
+             jnp.zeros((64, 2), jnp.float32)))
+        text = res.train_step.lower(*args).compile().as_text()
+    named = _all_names(text)
+    for name in STEP_SCOPES:
+        assert name in named, name
+    compiled = _scoped_ops(text)
+    covered = sum(bool(_names(op) & set(STEP_SCOPES))
+                  for _, _, op in compiled)
+    assert covered >= 0.95 * len(compiled), (covered, len(compiled))
+    kernels = [n for n, kind, op in compiled
+               if kind == "custom-call" and "jet_dense_pallas" in op]
+    assert len(kernels) >= 2 * 3          # grid and cross, three layers
+    assert all("jet_dense" in n for n in kernels), kernels
+
+
+def test_act_jet_backward_carries_its_scope(one_chip, monkeypatch):
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    text = compile_for_chip(
+        jax.grad(lambda c: jnp.sum(ops.act_jet(c, "tanh"))),
+        (3, BATCH, WIDTHS[0]), sharding=one_chip)
+    assert "kernel.act_jet.bwd" in _all_names(text)
