@@ -9,12 +9,18 @@ An engine answers three questions about any :class:`repro.core.network.Network`:
   axis, (d_in, order+1, N, d_out), with the direction axis folded into the
   batch so the whole grid is ONE forward (a single Pallas launch per layer);
 * ``cross(net, params, x, axes)`` -- the mixed partial
-  ``d^m f / dx_{a_1}..dx_{a_m}``, (N, d_out), by polarization of 2^m
+  ``d^m f / dx_{a_1}..dx_{a_m}``, (N, d_out), by polarization of
   directional derivatives (never a nested-autodiff graph).
 
 ``grid`` and ``cross`` are engine-generic: they are assembled from ``derivs``
 here in the base class, so a new engine implements one method and inherits
-the whole surface.  Shipped engines:
+the whole surface.  Both ask :meth:`DerivativeEngine.directional` for jets
+along static integer directions.  A :class:`PolarizationPlan` says which:
+the 2^m sign patterns of a mixed partial come in pairs that give the same
+term, and patterns that give a zero, repeated or scaled direction collapse
+onto one primitive direction.  :func:`table_engine` runs the directions of
+a whole derivative table (pure and mixed) as ONE jet forward and answers
+every ``grid`` and ``cross`` of that table from it.  Shipped engines:
 
 =====================  =====================================================
 ``NTPEngine(impl)``    the paper's quasilinear jet forward (Algorithm 1);
@@ -51,15 +57,107 @@ from __future__ import annotations
 import itertools
 import math
 from dataclasses import dataclass
-from typing import Sequence
+from typing import Dict, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.runtime.metrics import scope
+from repro.runtime.metrics import count, scope
 
 from . import jet as J
 from .network import Network
+
+Direction = Tuple[int, ...]
+
+
+def axis_directions(d_in: int) -> Tuple[Direction, ...]:
+    """The coordinate axes e_0 .. e_{d_in-1}: the grid's directions."""
+    return tuple(tuple(int(i == a) for i in range(d_in))
+                 for a in range(d_in))
+
+
+def _primitive(v: Sequence[int]) -> Tuple[Direction, int]:
+    """``v == c * p`` with ``p`` integer, its entries coprime and its first
+    nonzero entry positive: (p, c)."""
+    c = math.gcd(*v)
+    if next(a for a in v if a) < 0:
+        c = -c
+    return tuple(a // c for a in v), c
+
+
+@dataclass(frozen=True)
+class PolarizationPlan:
+    """The directional jets a set of mixed partials needs, and the static
+    weights that assemble each partial from them.
+
+    The polarization identity
+
+        D_{v_1..v_m} f = 1/(2^m m!) sum_{eps in {+-1}^m}
+                         (prod_k eps_k) D^m_{sum_k eps_k v_k} f
+
+    with ``v_k = e_{axes[k]}`` sums 2^m order-m directional derivatives.
+    Patterns eps and -eps give the same term (along -v the order-m
+    derivative is (-1)^m times the one along v, and so is the weight), so
+    only eps_1 = +1 is run, at twice the weight.  A zero direction adds
+    nothing; along c * p the order-m derivative is c^m times the one along
+    p, so every direction is reduced to its primitive ``p`` and the terms
+    on one ``p`` are merged into one integer weight.
+
+    ``directions`` are the primitive directions, each once; ``terms[t]``
+    lists ``(index into directions, integer weight)`` of the t-th partial,
+    whose value is the weighted sum of the order-``orders[t]`` derivatives
+    over ``2^m m!``.
+    """
+
+    directions: Tuple[Direction, ...]
+    terms: Tuple[Tuple[Tuple[int, int], ...], ...]
+    orders: Tuple[int, ...]
+
+    @staticmethod
+    def build(d_in: int, mixed: Sequence[Sequence[int]],
+              axes: bool = False) -> "PolarizationPlan":
+        """The plan of the partials ``mixed`` (axis tuples) over ``d_in``
+        inputs; with ``axes`` the coordinate axes come first, in grid
+        order, so the plan serves a whole derivative table.  The other
+        directions follow in the order the terms first need them."""
+        index: Dict[Direction, int] = {}
+        if axes:
+            index.update((e, a) for a, e in enumerate(axis_directions(d_in)))
+        terms = []
+        for term in mixed:
+            m = len(term)
+            weights: Dict[Direction, int] = {}
+            for tail in itertools.product((1, -1), repeat=m - 1):
+                eps = (1,) + tail
+                v = [0] * d_in
+                for e, a in zip(eps, term):
+                    v[a] += e
+                if not any(v):
+                    continue
+                p, c = _primitive(v)
+                weights[p] = weights.get(p, 0) + 2 * math.prod(eps) * c ** m
+            terms.append(tuple((index.setdefault(p, len(index)), w)
+                               for p, w in weights.items()))
+        return PolarizationPlan(tuple(index), tuple(terms),
+                                tuple(len(t) for t in mixed))
+
+    def order(self, pure_order: int) -> int:
+        """The jet order that serves every term (and pure derivatives up
+        to ``pure_order``)."""
+        return max((pure_order, *self.orders))
+
+    def assemble(self, jets: jnp.ndarray, t: int) -> jnp.ndarray:
+        """The t-th partial, (N, d_out), from ``jets`` (n_dirs, >= m+1, N,
+        d_out) along ``directions``.  The weights are static: the terms are
+        added in a fixed order (not a matmul), so every launch -- one device
+        or a mesh -- sums the same way."""
+        m = self.orders[t]
+        top = None
+        for i, w in self.terms[t]:
+            term = w * jets[i, m]
+            top = term if top is None else top + term
+        return top / (2.0 ** m * math.factorial(m))
+
 
 # accepted alternate spellings -> canonical engine name
 _SPEC_ALIASES = {"jax-jet": "jet", "jaxjet": "jet"}
@@ -159,44 +257,38 @@ class DerivativeEngine:
             return jnp.moveaxis(d.reshape((order + 1, n_dirs, batch, -1)),
                                 1, 0)
 
+    def directional(self, net: Network, params, x: jnp.ndarray,
+                    dirs: Sequence[Direction], order: int) -> jnp.ndarray:
+        """(len(dirs), order+1, N, d_out): derivatives along each static
+        integer direction in ``dirs``, in one folded forward."""
+        return self._batched_directional(net, params, x,
+                                         jnp.asarray(dirs, x.dtype), order)
+
     def grid(self, net: Network, params, x: jnp.ndarray,
              order: int) -> jnp.ndarray:
         """Pure derivatives along every coordinate axis:
         (d_in, order+1, N, d_out)."""
         with scope("ntp.grid"):
-            eye = jnp.eye(x.shape[-1], dtype=x.dtype)
-            return self._batched_directional(net, params, x, eye, order)
+            return self.directional(net, params, x,
+                                    axis_directions(x.shape[-1]), order)
 
     def cross(self, net: Network, params, x: jnp.ndarray,
               axes: Sequence[int]) -> jnp.ndarray:
         """Mixed partial ``d^m f / dx_{axes[0]} ... dx_{axes[m-1]}``, (N, d_out),
-        via the polarization identity
-
-            D_{v_1..v_m} f = 1/(2^m m!) sum_{eps in {+-1}^m}
-                             (prod_k eps_k) D^m_{sum_k eps_k v_k} f
-
-        with ``v_k = e_{axes[k]}``.  Repeated axes are allowed
-        (``axes=(0, 0, 1)`` gives u_xxy)."""
+        via the polarization identity (:class:`PolarizationPlan`) over the
+        distinct primitive directions of ``sum_k eps_k e_{axes[k]}``: at
+        most 2^(m-1) of them.  Repeated axes are allowed (``axes=(0, 0,
+        1)`` gives u_xxy)."""
         m, d = len(axes), x.shape[-1]
         if m == 0:
             raise ValueError("axes must name at least one differentiation axis")
         if any(a < 0 or a >= d for a in axes):
             raise ValueError(f"axes {tuple(axes)} out of range for d_in={d}")
-        signs = list(itertools.product((1.0, -1.0), repeat=m))
+        plan = PolarizationPlan.build(d, (tuple(axes),))
         with scope("ntp.cross"):
-            basis = jnp.eye(d, dtype=x.dtype)[jnp.asarray(axes)]   # (m, d)
-            dirs = jnp.asarray(signs, x.dtype) @ basis              # (2^m, d)
-            derivs = self._batched_directional(net, params, x, dirs, m)
-            # the +-1 weights are static: add the signed terms in a fixed
-            # order (not a matmul), so every launch -- one device or a mesh
-            # -- sums the same way
+            jets = self.directional(net, params, x, plan.directions, m)
             with scope("ntp.polarize"):
-                top = None                                      # (N, d_out)
-                for i, eps in enumerate(signs):
-                    term = derivs[i, m] if math.prod(eps) > 0 \
-                        else -derivs[i, m]
-                    top = term if top is None else top + term
-                return top / (2.0 ** m * math.factorial(m))
+                return plan.assemble(jets, 0)
 
     # -- spec parsing -------------------------------------------------------
 
@@ -212,6 +304,59 @@ class DerivativeEngine:
 
     def __repr__(self) -> str:
         return f"{type(self).__name__}({self.spec!r})"
+
+
+class _TableJets(DerivativeEngine):
+    """An engine whose every ``directional`` request is answered from jets
+    already run: :func:`table_engine`'s view of a table's one forward.  A
+    direction the pass lacks is an error, never a second forward."""
+
+    def __init__(self, inner: DerivativeEngine,
+                 directions: Sequence[Direction], jets: jnp.ndarray):
+        self.inner = inner
+        self.index = {p: i for i, p in enumerate(directions)}
+        self.jets = jets                       # (n_dirs, order+1, N, d_out)
+
+    @property
+    def spec(self) -> str:
+        return self.inner.spec
+
+    def directional(self, net: Network, params, x: jnp.ndarray,
+                    dirs: Sequence[Direction], order: int) -> jnp.ndarray:
+        missing = [p for p in dirs if p not in self.index]
+        if missing:
+            raise KeyError(f"directions {missing} are not in this table's "
+                           f"pass (have {tuple(self.index)})")
+        return jnp.stack([self.jets[self.index[p], :order + 1]
+                          for p in dirs])
+
+
+def table_engine(engine: DerivativeEngine, net: Network, params,
+                 x: jnp.ndarray, order: int,
+                 mixed: Sequence[Sequence[int]]) -> DerivativeEngine:
+    """The engine a derivative table asks: pure derivatives up to ``order``
+    and the mixed partials ``mixed`` (axis tuples).  With mixed partials,
+    the coordinate axes and every direction of their
+    :class:`PolarizationPlan` run as ONE jet forward of ``engine`` under
+    ``ntp.grid``, at the order that serves them all (lower orders of a jet
+    do not depend on the higher ones), and the engine returned answers
+    ``grid`` and ``cross`` from that forward's rows.  Without, it is
+    ``engine`` itself.
+
+    Counts, at trace time, the jet rows per point the table runs
+    (``ntp.rows``) and the rows of a plain 2^m-direction polarization of
+    each partial beside the grid (``ntp.rows_polarized``)."""
+    d_in = x.shape[-1]
+    plan = PolarizationPlan.build(d_in, mixed, axes=True)
+    count("ntp.rows", len(plan.directions) * (plan.order(order) + 1))
+    count("ntp.rows_polarized", d_in * (order + 1)
+          + sum(2 ** len(a) * (len(a) + 1) for a in mixed))
+    if not mixed:
+        return engine
+    with scope("ntp.grid"):
+        jets = engine.directional(net, params, x, plan.directions,
+                                  plan.order(order))
+    return _TableJets(engine, plan.directions, jets)
 
 
 # ---------------------------------------------------------------------------

@@ -190,8 +190,9 @@ def cross(params: MLPParams, x: jnp.ndarray, axes: Sequence[int],
 
     with ``v_k = e_{axes[k]}``.  Repeated axes are allowed (``axes=(0, 0, 1)``
     gives u_xxy), so together with :func:`ntp_grid` this spans the full
-    nabla^m tensor from 2^m directional jets -- still one n-TangentProp batch,
-    never a nested-autodiff graph.
+    nabla^m tensor from at most 2^(m-1) distinct directional jets
+    (:class:`repro.core.engines.PolarizationPlan`) -- still one
+    n-TangentProp batch, never a nested-autodiff graph.
     """
     net, engine = _dense_view(params, activation, impl)
     return engine.cross(net, params, x, axes)

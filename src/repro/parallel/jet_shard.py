@@ -159,7 +159,7 @@ class ShardedEngine(DerivativeEngine):
         # polarization sum in ``cross`` would reduce over a device-sharded
         # direction axis -- a cross-device accumulation whose summation
         # order differs from the single-device launch (a 1-ULP f32 diff on
-        # 16-term order-4 polarizations).  The all-gather is pure data
+        # order-4 polarizations).  The all-gather is pure data
         # movement: every value stays bitwise identical, and the sum then
         # runs in the single-device order.
         return jax.device_put(

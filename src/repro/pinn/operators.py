@@ -24,7 +24,8 @@ residual reads exactly as the math.
 
 An :class:`Operator` declares its input dimension, the highest pure-
 derivative order it consumes, the mixed partials it needs (``mixed``, a
-tuple of axis tuples -- served through polarization, ``engine.cross``), a
+tuple of axis tuples -- served through polarization, ``engine.cross``,
+from the same one jet forward as the pure derivatives), a
 residual ``R(x, d)``, and an exact solution over its default domain box
 (shape (N,) for scalar operators, (N, d_out) for systems).  Registered:
 
@@ -61,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.engines import DerivativeEngine
+from repro.core.engines import DerivativeEngine, table_engine
 from repro.core.network import DenseMLP, Network
 from repro.core.ntp import MLPParams
 
@@ -133,9 +134,10 @@ class Operator:
     ``d_out`` is the number of unknown field components the residual reads
     from the table (``comp=`` indexing); the solving network must match.
     ``mixed`` lists the axis tuples of every ``d.mixed(...)`` lookup
-    the residual performs, so engines can precompute them (one polarization
-    batch each).  ``exact(x)`` is the solution the residual vanishes on --
-    (N,) for scalar operators, (N, d_out) for systems; it doubles as
+    the residual performs, so :func:`build_table` can precompute them (in
+    the same one jet forward as the pure derivatives).  ``exact(x)`` is the
+    solution the residual vanishes on -- (N,) for scalar operators,
+    (N, d_out) for systems; it doubles as
     boundary/initial data for training and as the accuracy oracle in tests.
     ``differentiable_exact`` is False when ``exact`` is not a pure
     jax function (e.g. the Burgers profile's bisection inversion), which
@@ -201,11 +203,16 @@ def check_net_matches(net: Network, op: Operator) -> None:
 
 def build_table(net: Network, params, engine: DerivativeEngine,
                 op: Operator, x: jnp.ndarray) -> DerivTable:
-    """Everything the residual will look up, precomputed in batched engine
-    calls: one ``grid`` for pure derivatives plus one polarization ``cross``
-    per declared mixed partial.  The component axis rides along for free:
-    the grid's trailing ``d_out`` axis becomes the table's ``comp=`` index."""
+    """Everything the residual will look up, from ONE jet forward
+    (:func:`repro.core.engines.table_engine`): the coordinate axes and the
+    polarization directions of every declared mixed partial run as one
+    batch, then ``grid`` reads the pure block and one ``cross`` per mixed
+    partial sums its directions out of that batch.  An operator with no
+    mixed partial is one plain ``grid``.  The component axis rides along
+    for free: the grid's trailing ``d_out`` axis becomes the table's
+    ``comp=`` index."""
     check_net_matches(net, op)
+    engine = table_engine(engine, net, params, x, op.order, op.mixed)
     pure = engine.grid(net, params, x, op.order)   # (d_in, n+1, N, d_out)
     mixed = {tuple(sorted(a)): engine.cross(net, params, x, a)   # (N, d_out)
              for a in op.mixed}

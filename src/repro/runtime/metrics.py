@@ -13,8 +13,9 @@ the ``op_name`` prefix of every device operation inside it, in a profiler
 trace it is a host event, and its host seconds and calls add up in an
 in-process registry.  The same registry keeps, per jitted function, what
 JAX's compile events report: jaxpr tracing, lowering to MLIR and the XLA
-compile (or persistent-cache load).  :func:`snapshot` reads it all,
-:func:`reset` empties it.
+compile (or persistent-cache load), and plain counters (:func:`count`,
+such as the jet rows a derivative table runs).  :func:`snapshot` reads it
+all, :func:`reset` empties it.
 """
 
 from __future__ import annotations
@@ -82,13 +83,19 @@ COMPILE_EVENTS = {
 
 _lock = threading.Lock()
 _counts: Dict[str, Dict[str, Tuple[int, float]]] = {
-    k: {} for k in ("span", *COMPILE_EVENTS.values())}
+    k: {} for k in ("span", *COMPILE_EVENTS.values(), "counter")}
 
 
-def _add(kind: str, name: str, seconds: float) -> None:
+def _add(kind: str, name: str, value: float) -> None:
     with _lock:
         n, s = _counts[kind].get(name, (0, 0.0))
-        _counts[kind][name] = (n + 1, s + seconds)
+        _counts[kind][name] = (n + 1, s + value)
+
+
+def count(name: str, value: float) -> None:
+    """Add ``value`` to the counter ``name``: its registry entry is
+    (calls, total)."""
+    _add("counter", name, value)
 
 
 @contextlib.contextmanager
@@ -124,7 +131,8 @@ def _on_compile_event(event: str, seconds: float, **kwargs) -> None:
 def snapshot() -> Dict[str, Dict[str, Tuple[int, float]]]:
     """``{kind: {name: (count, seconds)}}`` for the kinds ``span`` (a
     :func:`scope`), ``trace``, ``lower`` and ``compile`` (per jitted
-    function, since start-up or the last :func:`reset`)."""
+    function), and ``{name: (calls, total)}`` under ``counter`` (a
+    :func:`count`), since start-up or the last :func:`reset`."""
     with _lock:
         return {k: dict(v) for k, v in _counts.items()}
 

@@ -171,6 +171,30 @@ def _scoped_ops(text):
     return out
 
 
+def _compiled_step(one_chip, monkeypatch, op, width, depth, n_domain=64):
+    """The compiled text of ``op``'s ``ntp/pallas`` train step on a dense
+    tanh net, for the described chip."""
+    with jax.enable_x64(False):
+        res = train_operator(OperatorRunConfig(
+            op=op, width=width, depth=depth, n_domain=n_domain, n_bc=4,
+            adam_steps=0, engine="ntp/pallas", eval_pts_per_axis=2))
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+        d_in = get_operator(op).d_in
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (res.params, adam_init(res.params),
+             jnp.zeros((n_domain, d_in), jnp.float32)))
+        return res.train_step.lower(*args).compile().as_text()
+
+
+def _forward_kernels(compiled):
+    """Instruction names of the ``jet_dense`` Pallas custom calls (the
+    forward kernels: the backward recomputes in jnp)."""
+    return [n for n, kind, op in compiled
+            if kind == "custom-call" and "jet_dense_pallas" in op]
+
+
 def test_train_step_carries_the_layer_scopes(one_chip, monkeypatch):
     """Dense tanh net (width 8, depth 2), 64 points, ``ntp/pallas``, an
     order-3 operator with one mixed partial: every scope of the step names
@@ -185,17 +209,7 @@ def test_train_step_carries_the_layer_scopes(one_chip, monkeypatch):
         register(Operator(name=SCOPED_OP, d_in=2, order=3,
                           residual=_kdv_uxxt_residual, exact=kdv.exact,
                           domain=kdv.domain, mixed=((0, 1, 1),)))
-    with jax.enable_x64(False):
-        res = train_operator(OperatorRunConfig(
-            op=SCOPED_OP, width=8, depth=2, n_domain=64, n_bc=4,
-            adam_steps=0, engine="ntp/pallas", eval_pts_per_axis=2))
-        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-        args = jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip),
-            (res.params, adam_init(res.params),
-             jnp.zeros((64, 2), jnp.float32)))
-        text = res.train_step.lower(*args).compile().as_text()
+    text = _compiled_step(one_chip, monkeypatch, SCOPED_OP, width=8, depth=2)
     named = _all_names(text)
     for name in STEP_SCOPES:
         assert name in named, name
@@ -203,10 +217,45 @@ def test_train_step_carries_the_layer_scopes(one_chip, monkeypatch):
     covered = sum(bool(_names(op) & set(STEP_SCOPES))
                   for _, _, op in compiled)
     assert covered >= 0.95 * len(compiled), (covered, len(compiled))
-    kernels = [n for n, kind, op in compiled
-               if kind == "custom-call" and "jet_dense_pallas" in op]
-    assert len(kernels) >= 2 * 3          # grid and cross, three layers
+    kernels = _forward_kernels(compiled)
+    assert len(kernels) == 3          # grid and cross share one pass, 3 layers
     assert all("jet_dense" in n for n in kernels), kernels
+
+
+# Navier-Stokes-shaped: (x, y, t) -> (psi, p), order 3, the five mixed
+# partials of Raissi et al.'s momentum residuals
+NS_SHAPED_OP = "ns-shaped"
+NS_MIXED = ((0, 1), (0, 2), (1, 2), (0, 0, 1), (0, 1, 1))
+
+
+def _ns_shaped_residual(x, d):
+    u_x, u_t, v_t = d.mixed(0, 1), d.mixed(1, 2), -d.mixed(0, 2)
+    u_xx, v_yy = d.mixed(0, 0, 1), -d.mixed(0, 1, 1)
+    f = u_t + d(1, 1) * u_x + d(0, 1, comp=1) - 0.01 * (u_xx + d(1, 3))
+    g = v_t - d(0, 1) * u_x + d(1, 1, comp=1) - 0.01 * (v_yy - d(0, 3))
+    return jnp.stack([f, g])
+
+
+def _ns_shaped_exact(x):
+    psi = -jnp.cos(x[:, 0]) * jnp.cos(x[:, 1]) * jnp.exp(-0.02 * x[:, 2])
+    return jnp.stack([psi, 0.5 * psi], axis=1)
+
+
+def test_mixed_table_is_one_jet_forward(one_chip, monkeypatch):
+    """A train step whose derivative table holds five mixed partials
+    (dense tanh net, width 20, depth 8) runs one jet forward for the whole
+    table: one ``jet_dense`` launch per dense map, not one per engine
+    call."""
+    if NS_SHAPED_OP not in operator_names():
+        register(Operator(name=NS_SHAPED_OP, d_in=3, d_out=2, order=3,
+                          residual=_ns_shaped_residual,
+                          exact=_ns_shaped_exact,
+                          domain=((1.0, 8.0), (-2.0, 2.0), (0.0, 20.0)),
+                          mixed=NS_MIXED))
+    depth = 8
+    text = _compiled_step(one_chip, monkeypatch, NS_SHAPED_OP, width=20,
+                          depth=depth)
+    assert len(_forward_kernels(_scoped_ops(text))) == depth + 1
 
 
 def test_act_jet_backward_carries_its_scope(one_chip, monkeypatch):
