@@ -10,11 +10,11 @@ operator workloads through the same launcher surface as pinn-mlp.  The
 training-side knobs live on ``repro.pinn.OperatorRunConfig``: ``engine``
 takes a derivative-engine spec ("ntp", "ntp/pallas", "autodiff") and
 ``network`` a registered architecture built on the jet-module layer
-("dense", "mlp", "residual", "fourier", "transformer" -- see
+("dense", "mlp", "residual", "fourier", "transformer", "pinnsformer" -- see
 ``repro.core.network`` / ``repro.core.modules``); transformer extras ride
 ``net_kwargs`` (``{"n_heads": 2, "mlp_ratio": 2, "mask": None}``; ``mask``
 accepts ``None``/"none", ``"causal"``, or ``("local", W)`` and flows to
-``SelfAttention`` -- every variant runs through the same single-launch
+``Attention`` -- every variant runs through the same single-launch
 flash-jet kernel under ``ntp/pallas``; the attention trunk tokenizes the
 d_in input coordinates, so n_heads/head_dim below describe the default
 attention shape, not a sequence model).  d_in follows the operator

@@ -12,6 +12,9 @@ the paper removes, so every supported activation provides them in closed form:
 * ``softplus``:softplus' = sigmoid, so order-m derivatives reuse the sigmoid
                polynomials shifted by one.
 * ``sin``:     sigma^(m)(a) = sin(a + m*pi/2).
+* ``wave``:    PINNsFormer's w1 sin a + w2 cos a with learned w1, w2: its
+               stack (``wave_taylor_stack``) takes the weights, so it is not
+               a registry entry; ``repro.core.jet.wave`` composes it.
 * ``exp``:     sigma^(m) = exp.
 * ``identity``/``silu``/``gelu``: silu and (tanh-)gelu are *compositions* of
                the atoms above with products; they go through the jet algebra
@@ -126,6 +129,17 @@ def sin_taylor_stack(a: jnp.ndarray, n: int) -> jnp.ndarray:
         val = [jnp.sin, jnp.cos, lambda x: -jnp.sin(x), lambda x: -jnp.cos(x)][phase](a)
         rows.append(val * (1.0 / math.factorial(m)))
     return jnp.stack(rows)
+
+
+def wave_taylor_stack(a: jnp.ndarray, n: int, w1, w2) -> jnp.ndarray:
+    """(n+1, *a.shape) stack of W^(m)(a)/m! for the wavelet activation
+    W(a) = w1 sin a + w2 cos a (PINNsFormer), whose m-th derivative is
+    w1 sin(a + m pi/2) + w2 cos(a + m pi/2): one sin and one cos of ``a``
+    serve every order."""
+    s, c = jnp.sin(a), jnp.cos(a)
+    cycle = ((s, c), (c, -s), (-s, -c), (-c, s))   # (sin, cos)(a + m pi/2)
+    return jnp.stack([(w1 * cycle[m % 4][0] + w2 * cycle[m % 4][1])
+                      * (1.0 / math.factorial(m)) for m in range(n + 1)])
 
 
 def exp_taylor_stack(a: jnp.ndarray, n: int) -> jnp.ndarray:

@@ -247,15 +247,18 @@ class DerivativeEngine:
                              dirs: jnp.ndarray, order: int) -> jnp.ndarray:
         """(n_dirs, order+1, N, d_out): derivatives along each row of ``dirs``,
         with the direction axis folded into the batch -- one large forward
-        instead of a vmap over per-direction passes."""
+        instead of a vmap over per-direction passes.  For a network whose
+        output has a token axis, N counts the rows of
+        :func:`repro.core.network.token_points`."""
         n_dirs, batch = dirs.shape[0], x.shape[0]
         with scope("ntp.fold"):
             xt = jnp.tile(x, (n_dirs, 1))
             vt = jnp.repeat(dirs, batch, axis=0)
         d = self.derivs(net, params, xt, order, vt)
         with scope("ntp.fold"):
-            return jnp.moveaxis(d.reshape((order + 1, n_dirs, batch, -1)),
-                                1, 0)
+            # a token axis ahead of d_out folds into the point axis
+            return jnp.moveaxis(d.reshape((order + 1, n_dirs, -1,
+                                           d.shape[-1])), 1, 0)
 
     def directional(self, net: Network, params, x: jnp.ndarray,
                     dirs: Sequence[Direction], order: int) -> jnp.ndarray:
@@ -345,10 +348,16 @@ def table_engine(engine: DerivativeEngine, net: Network, params,
 
     Counts, at trace time, the jet rows per point the table runs
     (``ntp.rows``) and the rows of a plain 2^m-direction polarization of
-    each partial beside the grid (``ntp.rows_polarized``)."""
+    each partial beside the grid (``ntp.rows_polarized``); for a network
+    whose output has a token axis, also the token rows per point its
+    forward carries (``net.token_rows``: rows x tokens)."""
     d_in = x.shape[-1]
     plan = PolarizationPlan.build(d_in, mixed, axes=True)
-    count("ntp.rows", len(plan.directions) * (plan.order(order) + 1))
+    rows = len(plan.directions) * (plan.order(order) + 1)
+    count("ntp.rows", rows)
+    if hasattr(net, "token_points"):
+        points = jax.eval_shape(net.token_points, x).shape[0]
+        count("net.token_rows", rows * points // x.shape[0])
     count("ntp.rows_polarized", d_in * (order + 1)
           + sum(2 ** len(a) * (len(a) + 1) for a in mixed))
     if not mixed:

@@ -28,7 +28,8 @@ from typing import Callable, Sequence, Union
 import jax
 import jax.numpy as jnp
 
-from .activations import GELU_TANH_C, GELU_TANH_CUBIC, TAYLOR_STACKS
+from .activations import (GELU_TANH_C, GELU_TANH_CUBIC, TAYLOR_STACKS,
+                          wave_taylor_stack)
 from .partitions import faa_di_bruno_table
 
 # Every contraction on the derivative path (this algebra, the Pallas kernels,
@@ -331,8 +332,13 @@ def rsqrt(a: Jet) -> Jet:
 def compose(a: Jet, name: str) -> Jet:
     """sigma(a) for a registered smooth activation, via the Taylor-normalized
     Faa di Bruno contraction with closed-form outer coefficients."""
+    return compose_stack(a, TAYLOR_STACKS[name](a.coeffs[0], a.order))
+
+
+def compose_stack(a: Jet, fstack: jnp.ndarray) -> Jet:
+    """sigma(a) from the outer coefficients ``fstack`` (n+1, *shape),
+    ``F_m = sigma^(m)(a_0)/m!``, by the Faa di Bruno contraction."""
     n = a.order
-    fstack = TAYLOR_STACKS[name](a.coeffs[0], n)  # (n+1, *shape)
     rows = [fstack[0]]
     for k in range(1, n + 1):
         acc = None
@@ -357,6 +363,14 @@ def sigmoid(a: Jet) -> Jet:
 
 def sin(a: Jet) -> Jet:
     return compose(a, "sin")
+
+
+def wave(a: Jet, w1, w2) -> Jet:
+    """PINNsFormer's wavelet activation ``w1 sin a + w2 cos a`` with learned
+    scalars: one Faa di Bruno contraction over the outer coefficients of
+    :func:`repro.core.activations.wave_taylor_stack`, differentiable in
+    ``w1`` and ``w2``."""
+    return compose_stack(a, wave_taylor_stack(a.coeffs[0], a.order, w1, w2))
 
 
 def softplus(a: Jet) -> Jet:

@@ -9,8 +9,10 @@ module-agnostic.  A :class:`Module` is the smallest jet-traceable unit --
 * **leaves** own parameters and the jet rules for one operation --
   :class:`Dense` (with the Pallas ``jet_dense`` fast path and fused
   activation epilogue), :class:`Activation`, :class:`FourierFeatures`,
-  :class:`RMSNorm`, :class:`SelfAttention`, :class:`MLPBlock`,
-  :class:`CoordinateEmbedding`, :class:`TokenPool`;
+  :class:`RMSNorm`, :class:`Attention` (self- and cross-attention;
+  ``SelfAttention`` is its self case), :class:`MLPBlock`,
+  :class:`CoordinateEmbedding`, :class:`TokenPool`, and PINNsFormer's
+  :class:`Wave` and :class:`PseudoSequence`;
 * **combinators** own structure only -- :class:`Sequential` (params are a
   tuple, one entry per child, keys split once per child in order) and
   :class:`Residual` (``x + inner(x)``; jet addition is coefficient-wise and
@@ -28,7 +30,7 @@ attention layer through the single-launch ``ops.jet_flash_attention`` and
 rms_norm through ``ops.jet_rms_norm`` (the ``"flash_attention"`` /
 ``"rms_norm"`` ``FUSED_OP`` entries of the same typed epilogue registry);
 anything unfused runs the reference jet algebra, so a module mixes kernel
-and reference paths freely.  ``SelfAttention`` carries the attention-mask
+and reference paths freely.  ``Attention`` carries the attention-mask
 surface (``mask=None | "causal" | ("local", window)``, canonicalized by
 :func:`normalize_attention_mask`), honoured identically by the primal
 ``apply``, the jnp jet path (``J.softmax(mask=...)``), and the flash
@@ -38,7 +40,9 @@ Leaves register themselves in a name -> factory registry
 (:func:`register_module`) so configs and future conversion tools can build
 graphs from data.  New blocks implement the three methods and slot into any
 combinator; see ``repro.core.network.Transformer`` for the first non-MLP
-consumer (pre-norm self-attention trunk over coordinate tokens).
+consumer (pre-norm self-attention trunk over coordinate tokens) and
+``repro.core.network.PINNsFormer`` for an encoder-decoder whose decoder
+reads two streams.
 """
 
 from __future__ import annotations
@@ -49,6 +53,8 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from repro.runtime.metrics import scope
 
 from . import jet as J
 from .activations import PRIMALS
@@ -294,12 +300,18 @@ class RMSNorm(Module):
 
 
 @dataclass(frozen=True)
-class SelfAttention(Module):
-    """Multi-head scaled-dot-product self-attention over the token axis
-    (``x``: (..., T, dim)).  Scores are a jet x jet Cauchy-convolved einsum,
-    softmax goes through the exp/div power-series recurrences, and the value
-    contraction is a second jet x jet einsum -- the whole block stays inside
-    the quasilinear jet algebra (no nested autodiff anywhere).
+class Attention(Module):
+    """Multi-head scaled-dot-product attention over the token axis
+    (``x``: (..., T, dim)).  Queries come from ``x``; keys and values from
+    ``kv`` (cross-attention, e.g. a decoder reading an encoder's output),
+    or from ``x`` itself when ``kv`` is None: self-attention is the case in
+    which the key/value source is the query source (``SelfAttention``).
+    ``bias=True`` gives the q/k/v and output projections biases, as
+    ``torch.nn.MultiheadAttention`` has them.  Scores are a jet x jet
+    Cauchy-convolved einsum, softmax goes through the exp/div power-series
+    recurrences, and the value contraction is a second jet x jet einsum --
+    the whole block stays inside the quasilinear jet algebra (no nested
+    autodiff anywhere).
 
     ``mask`` opens sequence-structured workloads: ``None`` (dense),
     ``"causal"``, or ``("local", window)`` -- a causal sliding window where
@@ -313,11 +325,14 @@ class SelfAttention(Module):
     flash-jet launch (``ops.jet_flash_attention``, the ``"flash_attention"``
     registry entry): an online-softmax recurrence over KV blocks
     generalized to the coefficient axis, so the (Tq, Tk) score jet never
-    materializes."""
+    materializes.  The launch takes the query and key/value stacks apart,
+    so cross-attention is the same launch (at equal token counts); the
+    output bias adds to coefficient 0 after it."""
 
     dim: int
     n_heads: int = 2
     mask: Any = None
+    bias: bool = False
 
     def __post_init__(self):
         if self.dim % self.n_heads:
@@ -337,16 +352,28 @@ class SelfAttention(Module):
     def init(self, key: jax.Array, dtype=jnp.float32) -> Params:
         kq, kk, kv, ko = jax.random.split(key, 4)
         mk = lambda k: xavier_uniform(k, self.dim, self.dim, dtype)
-        return {"wq": mk(kq), "wk": mk(kk), "wv": mk(kv), "wo": mk(ko)}
+        params = {"wq": mk(kq), "wk": mk(kk), "wv": mk(kv), "wo": mk(ko)}
+        if self.bias:
+            params.update({b: jnp.zeros((self.dim,), dtype)
+                           for b in ("bq", "bk", "bv", "bo")})
+        return params
 
     def _split_heads(self, c: jnp.ndarray) -> jnp.ndarray:
         return c.reshape(c.shape[:-1] + (self.n_heads, self.head_dim))
 
+    @staticmethod
+    def _project(params: Params, z: jnp.ndarray, name: str) -> jnp.ndarray:
+        """The primal projection ``name`` (q, k, v or o), with its bias if
+        the block has biases."""
+        y = _matmul(z, params["w" + name])
+        return y + params["b" + name] if "b" + name in params else y
+
     def apply(self, params: Params, x: jnp.ndarray, *,
+              kv: jnp.ndarray | None = None,
               unroll: bool = False) -> jnp.ndarray:
-        q = self._split_heads(_matmul(x, params["wq"]))
-        k = self._split_heads(_matmul(x, params["wk"]))
-        v = self._split_heads(_matmul(x, params["wv"]))
+        kv = x if kv is None else kv
+        q, k, v = (self._split_heads(self._project(params, z, n))
+                   for z, n in ((x, "q"), (kv, "k"), (kv, "v")))
         s = jnp.einsum("...qhd,...khd->...hqk", q, k,
                        precision=J.MATMUL_PRECISION) / math.sqrt(self.head_dim)
         keep = attention_mask(self.mask, x.shape[-2])
@@ -355,14 +382,15 @@ class SelfAttention(Module):
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("...hqk,...khd->...qhd", p, v,
                        precision=J.MATMUL_PRECISION)
-        return _matmul(o.reshape(o.shape[:-2] + (self.dim,)), params["wo"])
+        return self._project(params, o.reshape(o.shape[:-2] + (self.dim,)),
+                             "o")
 
     def jet_apply(self, params: Params, jet: J.Jet, *,
-                  impl: str = "jnp") -> J.Jet:
-        split = lambda j: J.jmap(self._split_heads, j)
-        q = split(dense_jet(jet, params["wq"], None, None, impl))
-        k = split(dense_jet(jet, params["wk"], None, None, impl))
-        v = split(dense_jet(jet, params["wv"], None, None, impl))
+                  kv: J.Jet | None = None, impl: str = "jnp") -> J.Jet:
+        kv = jet if kv is None else kv
+        proj = lambda j, n: J.jmap(self._split_heads, dense_jet(
+            j, params["w" + n], params.get("b" + n), None, impl))
+        q, k, v = proj(jet, "q"), proj(kv, "k"), proj(kv, "v")
         scale = 1.0 / math.sqrt(self.head_dim)
         if impl == "pallas" and _has_epilogue("flash_attention"):
             # single tiled launch for the whole remaining block; the head
@@ -370,15 +398,78 @@ class SelfAttention(Module):
             # (which mixes heads) can fold in as the epilogue
             from repro.kernels import ops as kops
             to_heads = lambda c: jnp.moveaxis(c, -2, -3)   # (..., H, T, D)
-            return J.Jet(kops.jet_flash_attention(
+            out = kops.jet_flash_attention(
                 to_heads(q.coeffs), to_heads(k.coeffs), to_heads(v.coeffs),
-                params["wo"], scale, mask=self.mask))
+                params["wo"], scale, mask=self.mask)
+            if "bo" in params:
+                out = out.at[0].add(params["bo"])
+            return J.Jet(out)
         s = J.scale(J.einsum("...qhd,...khd->...hqk", q, k), scale)
         p = J.softmax(s, axis=-1,
                       mask=attention_mask(self.mask, jet.shape[-2]))
         o = J.einsum("...hqk,...khd->...qhd", p, v)
         o = J.jmap(lambda c: c.reshape(c.shape[:-2] + (self.dim,)), o)
-        return dense_jet(o, params["wo"], None, None, impl)
+        return dense_jet(o, params["wo"], params.get("bo"), None, impl)
+
+
+# self-attention is attention whose key/value source is its query source
+SelfAttention = Attention
+
+
+@dataclass(frozen=True)
+class Wave(Module):
+    """PINNsFormer's wavelet activation ``w1 sin x + w2 cos x`` (Zhao, Ding
+    & Prakash, ICLR 2024), with its own learned pair ``(w1, w2)``, both
+    starting at 1; params are the (2,) array.  The jet composes through the
+    jet algebra (``repro.core.jet.wave``) under either impl: the dense
+    kernel has no epilogue with learned weights.  Runs under the
+    ``net.wave`` scope."""
+
+    def init(self, key: jax.Array, dtype=jnp.float32) -> Params:
+        return jnp.ones((2,), dtype)
+
+    def apply(self, params: Params, x: jnp.ndarray, *,
+              unroll: bool = False) -> jnp.ndarray:
+        with scope("net.wave"):
+            return params[0] * jnp.sin(x) + params[1] * jnp.cos(x)
+
+    def jet_apply(self, params: Params, jet: J.Jet, *,
+                  impl: str = "jnp") -> J.Jet:
+        _check_impl(impl)
+        with scope("net.wave"):
+            return J.wave(jet, params[0], params[1])
+
+
+@dataclass(frozen=True)
+class PseudoSequence(Module):
+    """PINNsFormer's pseudo-sequence: a point x (..., d_in) becomes
+    ``tokens`` tokens (..., tokens, d_in), token i at x + i * step along the
+    last coordinate: time in PINNsFormer's (x, t) and in ``raissi-ns``'s
+    (x, y, t) (an operator with time first would shift x).  The map is
+    affine in the point, so its jet seeds the same tangent on every token
+    and shifts coefficient 0 only.  Runs under the ``net.seq`` scope."""
+
+    tokens: int
+    step: float
+
+    def offsets(self, d_in: int, dtype) -> jnp.ndarray:
+        """(tokens, d_in): token i's shift from the point."""
+        e = jnp.zeros((d_in,), dtype).at[-1].set(self.step)
+        return jnp.arange(self.tokens, dtype=dtype)[:, None] * e
+
+    def apply(self, params: Params, x: jnp.ndarray, *,
+              unroll: bool = False) -> jnp.ndarray:
+        with scope("net.seq"):
+            return x[..., None, :] + self.offsets(x.shape[-1], x.dtype)
+
+    def jet_apply(self, params: Params, jet: J.Jet, *,
+                  impl: str = "jnp") -> J.Jet:
+        _check_impl(impl)
+        with scope("net.seq"):
+            c = jet.coeffs[..., None, :]
+            c = jnp.broadcast_to(c, c.shape[:-2] + (self.tokens,)
+                                 + c.shape[-1:])
+            return J.Jet(c.at[0].add(self.offsets(jet.shape[-1], jet.dtype)))
 
 
 @dataclass(frozen=True)
@@ -531,6 +622,8 @@ for _name, _factory in (
     ("fourier_features", FourierFeatures),
     ("rms_norm", RMSNorm),
     ("self_attention", SelfAttention),
+    ("wave", Wave),
+    ("pseudo_sequence", PseudoSequence),
     ("mlp_block", MLPBlock),
     ("coordinate_embedding", CoordinateEmbedding),
     ("token_pool", TokenPool),

@@ -36,6 +36,9 @@ FourierFeatureMLP  random-feature embedding ``[sin 2pi Bx, cos 2pi Bx]`` in
 Transformer        pre-norm self-attention trunk over coordinate tokens
                    (the first non-MLP PINN architecture; softmax/einsum/
                    rms_norm all inside the quasilinear jet algebra)
+PINNsFormer        encoder-decoder attention over a pseudo-sequence of
+                   time-shifted points, wavelet activations; its output
+                   keeps the token axis, (N, tokens, d_out)
 =================  ==========================================================
 
 New architectures compose modules the same way (or register a factory with
@@ -55,9 +58,11 @@ import jax
 import jax.numpy as jnp
 
 from . import jet as J
-from .modules import (CoordinateEmbedding, Dense, FourierFeatures, MLPBlock,
-                      Module, Residual, RMSNorm, SelfAttention, Sequential,
-                      TokenPool)
+from repro.runtime.metrics import scope
+
+from .modules import (Attention, CoordinateEmbedding, Dense, FourierFeatures,
+                      MLPBlock, Module, PseudoSequence, Residual, RMSNorm,
+                      SelfAttention, Sequential, TokenPool, Wave)
 from .ntp import MLPParams, init_mlp, mlp_apply, xavier_uniform
 
 Params = Any  # parameter pytree; its structure is owned by the network
@@ -336,6 +341,141 @@ class Transformer(_Composed):
 
 
 # ---------------------------------------------------------------------------
+# PINNsFormer: encoder-decoder attention over a pseudo-sequence of points
+# ---------------------------------------------------------------------------
+
+@dataclass(frozen=True)
+class PINNsFormer:
+    """PINNsFormer (Zhao, Ding & Prakash, ICLR 2024, arXiv 2307.11833): a
+    point becomes a pseudo-sequence of ``tokens`` points, (x, t + i step)
+    for i < tokens (:class:`PseudoSequence`), embedded by ``Dense(d_in,
+    width)``.  ``depth`` encoder layers, each ``x + MHA(W(x), W(x), W(x))``
+    then ``x + FF(W(x))``, and a final ``W`` make the encoder output e;
+    ``depth`` decoder layers over the embedding, each ``x + MHA(W(x), e,
+    e)`` then ``x + FF(W(x))``, and a final ``W``; then a per-token head
+    ``Dense(width, head) W Dense(head, head) W Dense(head, d_out)``.  MHA
+    has biases (:class:`Attention` with ``bias=True``), FF is ``Dense(width,
+    ff) W Dense(ff, ff) W Dense(ff, width)``, and every ``W`` is a
+    :class:`Wave` with its own learned pair.
+
+    The output carries the token axis, (..., tokens, d_out): token i is the
+    solution at the point's i-th pseudo-sequence point, and its jet is the
+    derivative with respect to the point (all tokens move with it).  The
+    engines fold the token axis into the point axis
+    (:func:`token_points` gives the points the rows belong to).  The decoder
+    reads two streams, so the graph is written here rather than as a
+    ``Sequential``; attention runs under ``net.self_attn`` and
+    ``net.cross_attn``."""
+
+    d_in: int
+    width: int               # d_model
+    depth: int               # encoder layers, and as many decoder layers
+    d_out: int
+    n_heads: int = 2
+    ff: int = 256
+    head: int = 512
+    tokens: int = 5
+    step: float = 1e-4
+    activation: str = "wave"
+
+    def __post_init__(self):
+        if self.activation != "wave":
+            raise ValueError(f"pinnsformer's activation is the learned "
+                             f"wavelet 'wave', not {self.activation!r}")
+        if self.width % self.n_heads:
+            raise ValueError(f"width={self.width} not divisible by "
+                             f"n_heads={self.n_heads}")
+
+    # -- the graph's parts --------------------------------------------------
+    def _seq(self) -> PseudoSequence:
+        return PseudoSequence(self.tokens, self.step)
+
+    def _attn(self) -> Attention:
+        return Attention(self.width, self.n_heads, bias=True)
+
+    def _ff(self) -> Sequential:
+        return Sequential((Dense(self.width, self.ff), Wave(),
+                           Dense(self.ff, self.ff), Wave(),
+                           Dense(self.ff, self.width)))
+
+    def _head(self) -> Sequential:
+        return Sequential((Dense(self.width, self.head), Wave(),
+                           Dense(self.head, self.head), Wave(),
+                           Dense(self.head, self.d_out)))
+
+    def _layer_init(self, key: jax.Array, dtype) -> Params:
+        ka, kf = jax.random.split(key)
+        return {"wave_attn": Wave().init(key, dtype),
+                "attn": self._attn().init(ka, dtype),
+                "wave_ff": Wave().init(key, dtype),
+                "ff": self._ff().init(kf, dtype)}
+
+    def init(self, key: jax.Array, dtype=jnp.float32) -> Params:
+        ke, kh, *kl = jax.random.split(key, 2 + 2 * self.depth)
+        return {
+            "embed": Dense(self.d_in, self.width).init(ke, dtype),
+            "encoder": tuple(self._layer_init(k, dtype)
+                             for k in kl[:self.depth]),
+            "encoder_wave": Wave().init(ke, dtype),
+            "decoder": tuple(self._layer_init(k, dtype)
+                             for k in kl[self.depth:]),
+            "decoder_wave": Wave().init(ke, dtype),
+            "head": self._head().init(kh, dtype),
+        }
+
+    def _forward(self, params: Params, x, run: Callable, add: Callable):
+        """The graph once for both passes: ``run(module, params, x, **kw)``
+        is a module's ``apply`` or its ``jet_apply``, ``add`` the matching
+        sum."""
+        def layer(p, h, kv):
+            name = "net.self_attn" if kv is None else "net.cross_attn"
+            a = run(Wave(), p["wave_attn"], h)
+            with scope(name):
+                h = add(h, run(self._attn(), p["attn"], a,
+                               kv=a if kv is None else kv))
+            return add(h, run(self._ff(), p["ff"],
+                              run(Wave(), p["wave_ff"], h)))
+
+        src = run(Dense(self.d_in, self.width), params["embed"],
+                  run(self._seq(), (), x))
+        e = src
+        for p in params["encoder"]:
+            e = layer(p, e, None)
+        e = run(Wave(), params["encoder_wave"], e)
+        d = src
+        for p in params["decoder"]:
+            d = layer(p, d, e)
+        d = run(Wave(), params["decoder_wave"], d)
+        return run(self._head(), params["head"], d)
+
+    def apply(self, params: Params, x: jnp.ndarray, *,
+              unroll: bool = False) -> jnp.ndarray:
+        """(N, d_in) -> (N, tokens, d_out)."""
+        return self._forward(params, x,
+                             lambda m, p, h, **kw: m.apply(p, h, **kw),
+                             jnp.add)
+
+    def jet_apply(self, params: Params, jet: J.Jet, *,
+                  impl: str = "jnp") -> J.Jet:
+        return self._forward(
+            params, jet,
+            lambda m, p, h, **kw: m.jet_apply(p, h, impl=impl, **kw), J.add)
+
+    def token_points(self, x: jnp.ndarray) -> jnp.ndarray:
+        """(N * tokens, d_in): the point of every output token, token-minor,
+        in the order the folded output rows take."""
+        return self._seq().apply((), x).reshape(-1, self.d_in)
+
+
+def token_points(net: Network, x: jnp.ndarray) -> jnp.ndarray:
+    """The points a network's outputs belong to, one row per output row
+    once any token axis is folded into the point axis: ``x`` itself for a
+    network with no token axis, else ``net.token_points(x)``."""
+    points = getattr(net, "token_points", None)
+    return x if points is None else points(x)
+
+
+# ---------------------------------------------------------------------------
 # registry: named factories for configs / CLIs
 # ---------------------------------------------------------------------------
 
@@ -370,3 +510,4 @@ register_network("mlp", lambda *, d_in, d_out, width, depth, activation="tanh",
 register_network("residual", ResidualMLP)
 register_network("fourier", FourierFeatureMLP)
 register_network("transformer", Transformer)
+register_network("pinnsformer", PINNsFormer)

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.core import jet as J
 from repro.core.engines import DerivativeEngine
-from repro.core.network import Network
+from repro.core.network import Network, token_points
 from repro.core.ntp import MLPParams, mlp_apply
 from repro.runtime.metrics import scope
 
@@ -52,7 +52,9 @@ def pinn_loss(params, *, op: Union[Operator, str], pts: jnp.ndarray,
     """Operator-generic PINN objective: w_r ||R[u]||^2 + w_bc ||u - u*||^2_bd.
 
     ``bc_vals`` is the exact solution on ``bc_pts`` -- (N,) for scalar
-    operators, (N, d_out) for systems; precompute it outside jit
+    operators, (N, d_out) for systems; for a network whose output has a
+    token axis (PINNsFormer), on ``token_points(net, bc_pts)``, as the
+    residual is taken at ``token_points(net, pts)``.  Precompute it outside jit
     (``op.exact`` may be numpy-backed, e.g. the Burgers profile;
     :func:`repro.pinn.operators.exact_values` normalizes the shape).  For a
     multi-equation system the residual term averages the squares of every
@@ -72,10 +74,11 @@ def pinn_loss(params, *, op: Union[Operator, str], pts: jnp.ndarray,
         eng = ShardedEngine(eng, mesh)
     table = build_table(net, params, eng, op, pts)
     with scope("pinn.residual"):
-        r = op.residual(pts, table)
+        r = op.residual(token_points(net, pts), table)
         l_res = jnp.mean(r ** 2)
     with scope("pinn.boundary"):
-        ub = net.apply(params, bc_pts)                   # (Nb, d_out)
+        # (Nb, d_out); a token axis folds into the point axis
+        ub = net.apply(params, bc_pts).reshape(-1, net.d_out)
         bv = jnp.asarray(bc_vals)
         if bv.ndim == 1:
             bv = bv[:, None]

@@ -63,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engines import DerivativeEngine, table_engine
-from repro.core.network import DenseMLP, Network
+from repro.core.network import DenseMLP, Network, token_points
 from repro.core.ntp import MLPParams
 
 
@@ -213,6 +213,7 @@ def build_table(net: Network, params, engine: DerivativeEngine,
     ``comp=`` index."""
     check_net_matches(net, op)
     engine = table_engine(engine, net, params, x, op.order, op.mixed)
+    # a network with a token axis: N below counts token_points(net, x)
     pure = engine.grid(net, params, x, op.order)   # (d_in, n+1, N, d_out)
     mixed = {tuple(sorted(a)): engine.cross(net, params, x, a)   # (N, d_out)
              for a in op.mixed}
@@ -224,9 +225,11 @@ def residual_values(params, op: Operator, x: jnp.ndarray, *,
                     engine: Union[str, DerivativeEngine] = "ntp"
                     ) -> jnp.ndarray:
     """Pointwise residual of ``net`` under ``op``: (N,) for single-equation
-    operators, (n_eq, N) for systems."""
+    operators, (n_eq, N) for systems; N counts the rows of
+    ``token_points(net, x)`` for a network with a token axis."""
     eng = DerivativeEngine.from_spec(engine)
-    return op.residual(x, build_table(net, params, eng, op, x))
+    return op.residual(token_points(net, x),
+                       build_table(net, params, eng, op, x))
 
 
 def exact_values(op: Operator, x, dtype=None) -> jnp.ndarray:

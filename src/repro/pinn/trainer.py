@@ -18,7 +18,7 @@ import jax.numpy as jnp
 
 from repro.core.engines import DerivativeEngine
 from repro.core.jet import float_dtype
-from repro.core.network import Network, make_network
+from repro.core.network import Network, make_network, token_points
 from repro.core.ntp import MLPParams, init_mlp, num_params
 from repro.data.collocation import (boundary_grid, eval_grid, resample,
                                     sample_box, uniform_grid)
@@ -173,11 +173,13 @@ class OperatorRunConfig:
 
     ``engine`` accepts a spec string ("ntp", "ntp/pallas", "autodiff") or a
     :class:`DerivativeEngine` instance.  ``network`` names a registered
-    architecture ("dense", "mlp", "residual", "fourier", "transformer" --
-    any composition over the jet-module layer, see ``repro.core.modules``);
-    ``net_kwargs`` passes architecture extras (e.g. ``{"n_features": 32}``
-    for fourier, ``{"n_heads": 4, "mlp_ratio": 2}`` for transformer, whose
-    ``width`` must be divisible by ``n_heads``).  The network's output rank
+    architecture ("dense", "mlp", "residual", "fourier", "transformer",
+    "pinnsformer" -- any composition over the jet-module layer, see
+    ``repro.core.modules``); ``net_kwargs`` passes architecture extras (e.g.
+    ``{"n_features": 32}`` for fourier, ``{"n_heads": 4, "mlp_ratio": 2}``
+    for transformer, whose ``width`` must be divisible by ``n_heads``,
+    ``{"ff": 256, "head": 512, "tokens": 5, "step": 1e-4}`` for
+    pinnsformer, with ``activation="wave"``).  The network's output rank
     follows the operator (``op.d_out``), so multi-equation systems like
     "gray-scott" train with no extra plumbing.
     """
@@ -244,7 +246,7 @@ def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
         params = net.init(k_init, dtype=dtype)
 
         bc_pts = boundary_grid(op.domain, cfg.n_bc, dtype)
-        bc_vals = exact_values(op, bc_pts, dtype)
+        bc_vals = exact_values(op, token_points(net, bc_pts), dtype)
 
         def make_loss(eng):
             def loss_fn(p, pts):
@@ -322,8 +324,8 @@ def train_operator(cfg: OperatorRunConfig) -> OperatorResult:
 
     with scope(SETUP_SCOPE):
         xe = eval_grid(op.domain, cfg.eval_pts_per_axis, dtype)
-        u_net = net.apply(params, xe)               # (N, d_out)
-        u_true = exact_values(op, xe, dtype)
+        u_net = net.apply(params, xe).reshape(-1, net.d_out)   # (N, d_out)
+        u_true = exact_values(op, token_points(net, xe), dtype)
         l2 = float(jnp.sqrt(jnp.mean((u_net - u_true) ** 2)))
 
     return OperatorResult(params=params, op_name=op.name,

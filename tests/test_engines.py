@@ -247,7 +247,7 @@ def test_net_must_match_operator_rank():
 
 def test_network_registry():
     assert {"dense", "mlp", "residual", "fourier",
-            "transformer"} <= set(network_names())
+            "transformer", "pinnsformer"} <= set(network_names())
     net = make_network("fourier", d_in=3, d_out=1, width=8, depth=2,
                        n_features=4)
     assert net.d_in == 3 and net.d_out == 1

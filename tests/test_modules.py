@@ -186,7 +186,8 @@ def test_dense_pallas_epilogue_fallback():
 def test_module_registry():
     assert {"dense", "activation", "fourier_features", "rms_norm",
             "self_attention", "mlp_block", "coordinate_embedding",
-            "token_pool", "sequential", "residual"} <= set(module_names())
+            "token_pool", "sequential", "residual", "wave",
+            "pseudo_sequence"} <= set(module_names())
     mod = make_module("dense", d_in=3, d_out=4, activation="tanh")
     assert isinstance(mod, Dense)
     with pytest.raises(KeyError):

@@ -24,9 +24,10 @@ import pytest
 
 from repro.core import jet as J
 from repro.core.modules import (Activation, CoordinateEmbedding, Dense,
-                                FourierFeatures, MLPBlock, RMSNorm, Residual,
-                                SelfAttention, Sequential, TokenPool,
-                                module_names, normalize_attention_mask)
+                                FourierFeatures, MLPBlock, PseudoSequence,
+                                RMSNorm, Residual, SelfAttention, Sequential,
+                                TokenPool, Wave, module_names,
+                                normalize_attention_mask)
 from repro.core.network import make_network, network_names
 
 ORDERS = (0, 1, 2, 3, 4)
@@ -53,6 +54,8 @@ MODULE_CASES = {
     "sequential": lambda: (Sequential((Dense(4, 8, "sigmoid"),
                                        Dense(8, 2, None))), (3, 4)),
     "residual": lambda: (Residual(Dense(6, 6, "tanh")), (3, 6)),
+    "wave": lambda: (Wave(), (3, 2, 5)),
+    "pseudo_sequence": lambda: (PseudoSequence(3, 0.1), (3, 2)),
 }
 
 # one case per registered network: extra make_network kwargs
@@ -62,6 +65,8 @@ NETWORK_KWARGS = {
     "residual": {},
     "fourier": {"n_features": 4},
     "transformer": {"n_heads": 2},
+    "pinnsformer": {"activation": "wave", "n_heads": 2, "ff": 16, "head": 16,
+                    "tokens": 3},
 }
 
 

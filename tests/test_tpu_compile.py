@@ -264,3 +264,43 @@ def test_act_jet_backward_carries_its_scope(one_chip, monkeypatch):
         jax.grad(lambda c: jnp.sum(ops.act_jet(c, "tanh"))),
         (3, BATCH, WIDTHS[0]), sharding=one_chip)
     assert "kernel.act_jet.bwd" in _all_names(text)
+
+
+# PINNsFormer (d_model 32, 2 heads of 16, as published; FF and head cut to
+# 64 to keep the compile short) on the Navier-Stokes-shaped operator
+PFNS_SCOPES = ("net.self_attn", "net.cross_attn", "net.wave", "net.seq",
+               "kernel.jet_dense.bwd", "kernel.flash_attention.bwd")
+
+
+def test_pinnsformer_step_runs_both_kernels(one_chip, monkeypatch):
+    """The ``ntp/pallas`` train step of a PINNsFormer calls the ``jet_dense``
+    and ``jet_flash_attention`` kernels compiled, and its operations carry
+    the network's scopes and both kernels' backward scopes."""
+    if NS_SHAPED_OP not in operator_names():
+        register(Operator(name=NS_SHAPED_OP, d_in=3, d_out=2, order=3,
+                          residual=_ns_shaped_residual,
+                          exact=_ns_shaped_exact,
+                          domain=((1.0, 8.0), (-2.0, 2.0), (0.0, 20.0)),
+                          mixed=NS_MIXED))
+    n_domain = 16
+    with jax.enable_x64(False):
+        res = train_operator(OperatorRunConfig(
+            op=NS_SHAPED_OP, network="pinnsformer", width=32, depth=1,
+            activation="wave",
+            net_kwargs={"n_heads": 2, "ff": 64, "head": 64, "tokens": 5},
+            n_domain=n_domain, n_bc=4, adam_steps=0, engine="ntp/pallas",
+            eval_pts_per_axis=2))
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+        args = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (res.params, adam_init(res.params),
+             jnp.zeros((n_domain, 3), jnp.float32)))
+        text = res.train_step.lower(*args).compile().as_text()
+    calls = [n for n, kind, _ in _scoped_ops(text) if kind == "custom-call"]
+    # embed, 3 + 3 FF per layer, 3 head maps; one flash launch per layer
+    assert sum(n.startswith("jet_dense") for n in calls) == 1 + 2 * 6 + 3
+    assert sum(n.startswith("jet_flash_attention") for n in calls) == 2
+    named = _all_names(text)
+    for name in PFNS_SCOPES:
+        assert name in named, name
