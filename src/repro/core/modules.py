@@ -54,7 +54,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.runtime.metrics import scope
+from repro.runtime.metrics import count, scope
 
 from . import jet as J
 from .activations import PRIMALS
@@ -420,9 +420,12 @@ SelfAttention = Attention
 class Wave(Module):
     """PINNsFormer's wavelet activation ``w1 sin x + w2 cos x`` (Zhao, Ding
     & Prakash, ICLR 2024), with its own learned pair ``(w1, w2)``, both
-    starting at 1; params are the (2,) array.  The jet composes through the
-    jet algebra (``repro.core.jet.wave``) under either impl: the dense
-    kernel has no epilogue with learned weights.  Runs under the
+    starting at 1; params are the (2,) array.  Under ``impl="pallas"`` the
+    jet runs the ``wave_jet`` kernels (``repro.kernels.ops.wave_jet``, the
+    ``"wave"`` ``FUSED_OP`` registry entry: one launch forward, one
+    backward), counted once per dispatch at trace time under
+    ``net.wave_jets``; under ``"jnp"`` it composes through the jet algebra
+    (``repro.core.jet.wave``), the kernels' oracle.  Runs under the
     ``net.wave`` scope."""
 
     def init(self, key: jax.Array, dtype=jnp.float32) -> Params:
@@ -437,6 +440,10 @@ class Wave(Module):
                   impl: str = "jnp") -> J.Jet:
         _check_impl(impl)
         with scope("net.wave"):
+            if impl == "pallas" and _has_epilogue("wave"):
+                from repro.kernels import ops as kops
+                count("net.wave_jets", 1)
+                return J.Jet(kops.wave_jet(jet.coeffs, params))
             return J.wave(jet, params[0], params[1])
 
 

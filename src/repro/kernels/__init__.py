@@ -8,6 +8,8 @@ transformer trunk runs ``jet_flash_attention`` -- the WHOLE attention layer
 contraction, output projection) in a single launch whose working set is
 bounded by its block sizes, never the materialized (T, T) score jet -- and
 ``jet_rms_norm`` (mean-square convolution + rsqrt recurrence + gain).
+PINNsFormer's learned wavelet runs ``wave_jet``: one launch forward and one
+backward (``wave_jet.py``).
 ``jet_attention_scores`` is the PR-5 materializing score kernel, kept for
 benchmarking against.  ``ref.py`` holds the pure-jnp oracles the test sweeps
 compare against; ``ops.epilogues()`` is the typed registry modules consult
@@ -16,4 +18,4 @@ before dispatching here.
 
 from . import ops, ref
 from .ops import (EpilogueKind, act_jet, epilogues, jet_attention_scores,
-                  jet_dense, jet_flash_attention, jet_rms_norm)
+                  jet_dense, jet_flash_attention, jet_rms_norm, wave_jet)

@@ -20,8 +20,8 @@ This is also the dispatch surface for the compositional module layer
   fusable name to :class:`EpilogueKind`.  ``ACTIVATION`` entries are the
   closed-form Taylor tables the dense kernel can run in its Faa di Bruno
   epilogue; ``FUSED_OP`` entries ("rms_norm", "attention_scores",
-  "flash_attention") name dedicated whole-chain kernels reached via their
-  own dispatch functions and are NOT valid dense epilogues.  The
+  "flash_attention", "wave") name dedicated whole-chain kernels reached
+  via their own dispatch functions and are NOT valid dense epilogues.  The
   pre-redesign boolean pair ``supports_epilogue`` /
   ``supports_activation_epilogue`` is gone (it survived one PR as
   deprecated shims after the registry landed).
@@ -45,6 +45,7 @@ from .jet_attention import (jet_attention_scores_pallas,
 from .jet_dense import jet_dense_pallas
 from .tanh_jet import KERNEL_ACTS as _KERNEL_ACTS
 from .tanh_jet import act_jet_pallas
+from .wave_jet import wave_jet_bwd_pallas, wave_jet_pallas
 
 
 def _on_tpu() -> bool:
@@ -66,8 +67,9 @@ class EpilogueKind(enum.Enum):
         Faa di Bruno epilogue (also valid standalone via ``act_jet``);
     ``FUSED_OP``
         a dedicated whole-chain kernel (rms_norm, the PR-5 materializing
-        attention scores, the tiled flash attention block) reached through
-        its own dispatch function -- never a dense epilogue.
+        attention scores, the tiled flash attention block, the wavelet jet
+        with its learned weights) reached through its own dispatch
+        function -- never a dense epilogue.
     """
 
     ACTIVATION = "activation"
@@ -81,6 +83,7 @@ _EPILOGUE_KINDS: dict = {
     "rms_norm": EpilogueKind.FUSED_OP,
     "attention_scores": EpilogueKind.FUSED_OP,
     "flash_attention": EpilogueKind.FUSED_OP,
+    "wave": EpilogueKind.FUSED_OP,
 }
 
 
@@ -330,4 +333,44 @@ def jet_rms_norm(coeffs: jnp.ndarray, gamma: jnp.ndarray,
     axes (token axis included) fold into the kernel batch dimension."""
     flat, batch = _fold_batch(coeffs)
     out = _rms_norm3(flat, gamma, eps)
+    return out.reshape(out.shape[:1] + batch + out.shape[-1:])
+
+
+# ---------------------------------------------------------------------------
+# the wavelet jet (PINNsFormer's w1 sin + w2 cos, learned weights): one
+# launch forward and one backward (kernels/wave_jet.py), the "wave"
+# registry entry.  Unlike the ops above, the backward is a kernel of its
+# own, the closed-form transpose of the Faa di Bruno contraction, so the
+# only residuals are the stack and the weights and no recompute runs in XLA.
+# ---------------------------------------------------------------------------
+
+def _wave_jet_impl(coeffs, w):
+    return wave_jet_pallas(coeffs, w, interpret=not _on_tpu())
+
+
+@jax.custom_vjp
+def _wave_jet3(coeffs: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    return _wave_jet_impl(coeffs, w)
+
+
+def _wave_jet_fwd(coeffs, w):
+    return _wave_jet_impl(coeffs, w), (coeffs, w)
+
+
+@scope("kernel.wave_jet.bwd")
+def _wave_jet_bwd(res, g):
+    coeffs, w = res
+    return wave_jet_bwd_pallas(coeffs, w, g, interpret=not _on_tpu())
+
+
+_wave_jet3.defvjp(_wave_jet_fwd, _wave_jet_bwd)
+
+
+def wave_jet(coeffs: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """Wavelet activation jet ``w1 S + w2 C`` (S, C the sin- and cos-jets
+    of the stack): (n+1, *batch, W) and the (2,) weights -> same shape,
+    differentiable in both.  Leading batch axes (a token axis included)
+    fold into the kernel's row axis and unfold on the way out."""
+    flat, batch = _fold_batch(coeffs)
+    out = _wave_jet3(flat, w)
     return out.reshape(out.shape[:1] + batch + out.shape[-1:])

@@ -58,6 +58,15 @@ def act_jet_ref(coeffs: jnp.ndarray, activation: str = "tanh") -> jnp.ndarray:
     return jnp.stack(rows)
 
 
+def wave_jet_ref(coeffs: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """PINNsFormer's wavelet jet ``w1 sin + w2 cos`` of a (n+1, ...) stack:
+    the sin jet of the stack and of the stack shifted by pi/2 (cos a =
+    sin(a + pi/2)), weighted."""
+    shifted = coeffs.at[0].add(jnp.pi / 2)
+    return (w[0] * act_jet_ref(coeffs, "sin")
+            + w[1] * act_jet_ref(shifted, "sin"))
+
+
 def jet_dense_ref(coeffs: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray,
                   activation: str | None = "tanh") -> jnp.ndarray:
     """Fused layer oracle: (n+1, B, Din) @ (Din, Dout) + bias-on-c0, then

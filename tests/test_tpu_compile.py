@@ -30,6 +30,7 @@ from repro.kernels.jet_attention import (jet_flash_attention_pallas,
                                          jet_rms_norm_pallas)
 from repro.kernels.jet_dense import jet_dense_pallas
 from repro.kernels.tanh_jet import act_jet_pallas
+from repro.kernels.wave_jet import wave_jet_bwd_pallas, wave_jet_pallas
 from repro.optim import adam_init
 from repro.pinn import OperatorRunConfig, train_operator
 from repro.pinn.operators import (Operator, get_operator, operator_names,
@@ -110,6 +111,31 @@ def test_jet_flash_attention_compiles(one_chip, width, order):
             q, k, v, wo, 0.25, interpret=False),
         qkv, qkv, qkv, (HEADS, width // HEADS, width), sharding=one_chip)
     assert "tpu_custom_call" in text
+
+
+# PINNsFormer's wave widths: d_model 32, FF 256, head 512; token rows of a
+# point set that fills no whole block, so the ragged last block compiles too
+WAVE_WIDTHS = (32, 256, 512)
+WAVE_ROWS = BATCH * 5 + 40
+PALLAS_CALL = re.compile(r'^\s*(?:ROOT )?%(\S+) = .*'
+                         r'custom_call_target="tpu_custom_call"', re.M)
+
+
+@pytest.mark.parametrize("order", (1, 2, 3, 4))
+@pytest.mark.parametrize("width", WAVE_WIDTHS)
+def test_wave_jet_compiles(one_chip, width, order):
+    """Forward and backward wavelet-jet kernels, each one launch named
+    after its ``pallas_call``."""
+    stack = (order + 1, WAVE_ROWS, width)
+    fwd = compile_for_chip(
+        lambda c, w: wave_jet_pallas(c, w, interpret=False),
+        stack, (2,), sharding=one_chip)
+    bwd = compile_for_chip(
+        lambda c, w, g: wave_jet_bwd_pallas(c, w, g, interpret=False),
+        stack, (2,), stack, sharding=one_chip)
+    for text, name in ((fwd, "wave_jet"), (bwd, "wave_jet_bwd")):
+        calls = PALLAS_CALL.findall(text)
+        assert [n.rsplit(".", 1)[0] for n in calls] == [name], calls
 
 
 @pytest.mark.parametrize("order", ORDERS)
@@ -269,13 +295,20 @@ def test_act_jet_backward_carries_its_scope(one_chip, monkeypatch):
 # PINNsFormer (d_model 32, 2 heads of 16, as published; FF and head cut to
 # 64 to keep the compile short) on the Navier-Stokes-shaped operator
 PFNS_SCOPES = ("net.self_attn", "net.cross_attn", "net.wave", "net.seq",
-               "kernel.jet_dense.bwd", "kernel.flash_attention.bwd")
+               "kernel.jet_dense.bwd", "kernel.flash_attention.bwd",
+               "kernel.wave_jet.bwd")
+# Wave jets of a depth-1 PINNsFormer: two per encoder and decoder layer
+# (before the attention and before the FF), two inside each FF, the
+# encoder's and decoder's final ones, two in the head
+PFNS_WAVES = 12
 
 
 def test_pinnsformer_step_runs_both_kernels(one_chip, monkeypatch):
-    """The ``ntp/pallas`` train step of a PINNsFormer calls the ``jet_dense``
-    and ``jet_flash_attention`` kernels compiled, and its operations carry
-    the network's scopes and both kernels' backward scopes."""
+    """The ``ntp/pallas`` train step of a PINNsFormer calls the ``jet_dense``,
+    ``jet_flash_attention`` and ``wave_jet`` kernels compiled (one
+    ``wave_jet`` forward and one ``wave_jet_bwd`` launch per Wave jet), and
+    its operations carry the network's scopes and the kernels' backward
+    scopes."""
     if NS_SHAPED_OP not in operator_names():
         register(Operator(name=NS_SHAPED_OP, d_in=3, d_out=2, order=3,
                           residual=_ns_shaped_residual,
@@ -301,6 +334,49 @@ def test_pinnsformer_step_runs_both_kernels(one_chip, monkeypatch):
     # embed, 3 + 3 FF per layer, 3 head maps; one flash launch per layer
     assert sum(n.startswith("jet_dense") for n in calls) == 1 + 2 * 6 + 3
     assert sum(n.startswith("jet_flash_attention") for n in calls) == 2
+    kernels = [n.rsplit(".", 1)[0] for n in PALLAS_CALL.findall(text)]
+    assert kernels.count("wave_jet") == PFNS_WAVES
+    assert kernels.count("wave_jet_bwd") == PFNS_WAVES
     named = _all_names(text)
     for name in PFNS_SCOPES:
         assert name in named, name
+
+
+@pytest.mark.parametrize("engine,waves", [("ntp/pallas", PFNS_WAVES),
+                                          ("ntp", 0)])
+def test_pinnsformer_table_counts_its_wave_jets(one_chip, monkeypatch,
+                                                engine, waves):
+    """Lowering a PINNsFormer's derivative table for the chip counts
+    ``net.wave_jets`` once per Wave jet under ``ntp/pallas`` (each a
+    ``wave_jet`` kernel dispatch) and never under ``ntp`` (the jnp
+    algebra)."""
+    from repro.core.engines import DerivativeEngine
+    from repro.core.network import make_network
+    from repro.pinn.operators import build_table
+    from repro.runtime import metrics
+
+    if NS_SHAPED_OP not in operator_names():
+        register(Operator(name=NS_SHAPED_OP, d_in=3, d_out=2, order=3,
+                          residual=_ns_shaped_residual,
+                          exact=_ns_shaped_exact,
+                          domain=((1.0, 8.0), (-2.0, 2.0), (0.0, 20.0)),
+                          mixed=NS_MIXED))
+    op = get_operator(NS_SHAPED_OP)
+    net = make_network("pinnsformer", d_in=3, d_out=2, width=32, depth=1,
+                       activation="wave", n_heads=2, ff=64, head=64,
+                       tokens=5)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    counted = lambda: metrics.snapshot()["counter"].get("net.wave_jets",
+                                                        (0, 0.0))[1]
+    with jax.enable_x64(False):
+        shapes = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip),
+            (jax.eval_shape(lambda: net.init(jax.random.PRNGKey(0))),
+             jax.ShapeDtypeStruct((16, 3), jnp.float32)))
+        eng = DerivativeEngine.from_spec(engine)
+        before = counted()
+        text = jax.jit(lambda p, x: build_table(net, p, eng, op, x)._pure) \
+            .lower(*shapes).as_text()
+    assert counted() - before == waves
+    assert text.count("wave_jet") >= waves
