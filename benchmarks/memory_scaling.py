@@ -1,6 +1,8 @@
 """Paper section III-B memory claim: n-TangentProp is O(n M) while nested
 autodiff's graph is O(M^n).  Measured here as compiled temp-buffer bytes from
-XLA's memory analysis (no wall clock needed).
+XLA's memory analysis (no wall clock needed), on the paper's 3x24
+``DenseMLP``; the autodiff side is ``AutodiffEngine``, nested ``jax.grad``
+for this scalar-output net.  CPU figures, not chip evidence.
 
 The second sweep makes the flash-attention memory claim the same way: the
 PR-5 materializing score kernel's temp footprint grows with T^2 (it holds
@@ -16,7 +18,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core import baselines, init_mlp, ntp_derivatives
+from repro.core import AutodiffEngine, DenseMLP, NTPEngine
 
 from .common import csv_row
 
@@ -60,13 +62,15 @@ def _attention_rows(order: int):
 
 
 def run(max_order: int = 6, batch: int = 256, attn_order: int = 2):
-    key = jax.random.PRNGKey(0)
-    params = init_mlp(key, 1, 24, 3, 1, dtype=jnp.float32)
+    net = DenseMLP(1, 24, 3, 1)
+    ntp, autodiff = NTPEngine(), AutodiffEngine()
+    params = net.init(jax.random.PRNGKey(0), dtype=jnp.float32)
     x = jax.random.uniform(jax.random.PRNGKey(1), (batch, 1), jnp.float32, -1, 1)
     rows = []
     for n in (1, 2, 4, max_order):
-        m_ntp = _temp_bytes(lambda p, x, n=n: ntp_derivatives(p, x, n), params, x)
-        m_ad = _temp_bytes(lambda p, x, n=n: baselines.nested_jacfwd(p, x, n),
+        m_ntp = _temp_bytes(lambda p, x, n=n: ntp.derivs(net, p, x, n),
+                            params, x)
+        m_ad = _temp_bytes(lambda p, x, n=n: autodiff.derivs(net, p, x, n),
                            params, x)
         rows.append(csv_row(f"membytes_ntp_n{n}", m_ntp / 1e6,
                             f"bytes={m_ntp}"))

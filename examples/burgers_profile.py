@@ -20,7 +20,7 @@ import argparse
 import jax
 import numpy as np
 
-from repro.core.ntp import mlp_apply
+from repro.core import DenseMLP
 from repro.pinn import PINNRunConfig, exact_profile, profile_lambda, train
 from repro.runtime.compile_cache import enable_compile_cache
 
@@ -52,7 +52,8 @@ def main():
     # accuracy vs the closed-form profile (C=1 normalization)
     xs = np.linspace(-cfg.domain, cfg.domain, 401)
     u_true = exact_profile(xs, args.k)
-    u_net = np.asarray(mlp_apply(res.params, jax.numpy.asarray(xs)[:, None]))[:, 0]
+    net = DenseMLP(1, cfg.width, cfg.depth, 1, cfg.activation)
+    u_net = np.asarray(net.apply(res.params, jax.numpy.asarray(xs)[:, None]))[:, 0]
     l2 = np.sqrt(np.mean((u_net - u_true) ** 2))
     print(f"L2 error vs exact profile: {l2:.3e}")
     print("lambda history:", [f"{l:.4f}" for l in res.lam_history[-8:]])

@@ -14,8 +14,7 @@ from .modules import (Activation, Attention, CoordinateEmbedding, Dense,
 from .network import (DenseMLP, MLP, FourierFeatureMLP, Network, PINNsFormer,
                       ResidualMLP, Transformer, make_network, network_names,
                       register_network, token_points)
-from .ntp import (MLPParams, cross, init_mlp, mlp_apply, ntp_derivatives,
-                  ntp_forward, ntp_grid, ntp_jet, num_params)
+from .ntp import MLPParams, init_mlp, num_params
 from .partitions import (bell_number, faa_di_bruno_table, partition_count,
                          partitions, raw_bell_coefficient, total_fdb_terms)
 
@@ -30,8 +29,7 @@ __all__ = [
     "DenseMLP", "MLP", "FourierFeatureMLP", "Network", "PINNsFormer",
     "ResidualMLP", "Transformer", "make_network", "network_names",
     "register_network", "token_points",
-    "MLPParams", "cross", "init_mlp", "mlp_apply", "ntp_derivatives",
-    "ntp_forward", "ntp_grid", "ntp_jet", "num_params",
+    "MLPParams", "init_mlp", "num_params",
     "bell_number", "faa_di_bruno_table", "partition_count", "partitions",
     "raw_bell_coefficient", "total_fdb_terms",
 ]

@@ -58,9 +58,16 @@ from repro.runtime.metrics import count, scope
 
 from . import jet as J
 from .activations import PRIMALS
-from .ntp import xavier_uniform
 
 Params = Any  # parameter pytree; structure owned by the module
+
+
+def xavier_uniform(key: jax.Array, fan_in: int, fan_out: int,
+                   dtype=jnp.float32) -> jnp.ndarray:
+    """Xavier-uniform weight init matching the paper's PyTorch defaults
+    (shared by every architecture in core/network.py)."""
+    lim = jnp.sqrt(6.0 / (fan_in + fan_out)).astype(dtype)
+    return jax.random.uniform(key, (fan_in, fan_out), dtype, -lim, lim)
 
 
 def _matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:

@@ -26,8 +26,8 @@ own the jet rules, the networks own only structure and the (stable) param
 layout.
 
 =================  ==========================================================
-DenseMLP           uniform-width MLP over :class:`repro.core.ntp.MLPParams`
-                   (fully backward-compatible with the seed API)
+DenseMLP           the paper's uniform-width MLP, over the stacked
+                   :class:`repro.core.ntp.MLPParams`
 MLP                variable per-layer widths
 ResidualMLP        pre-activation skip connections ``h <- h + act(W h + b)``
 FourierFeatureMLP  random-feature embedding ``[sin 2pi Bx, cos 2pi Bx]`` in
@@ -60,10 +60,12 @@ import jax.numpy as jnp
 from . import jet as J
 from repro.runtime.metrics import scope
 
+from .activations import PRIMALS
 from .modules import (Attention, CoordinateEmbedding, Dense, FourierFeatures,
                       MLPBlock, Module, PseudoSequence, Residual, RMSNorm,
-                      SelfAttention, Sequential, TokenPool, Wave)
-from .ntp import MLPParams, init_mlp, mlp_apply, xavier_uniform
+                      SelfAttention, Sequential, TokenPool, Wave,
+                      xavier_uniform)
+from .ntp import MLPParams, init_mlp
 
 Params = Any  # parameter pytree; its structure is owned by the network
 
@@ -113,29 +115,20 @@ class _Composed:
 
 
 # ---------------------------------------------------------------------------
-# DenseMLP: the paper's architecture, over the seed MLPParams pytree
+# DenseMLP: the paper's architecture, over the stacked MLPParams pytree
 # ---------------------------------------------------------------------------
 
 @dataclass(frozen=True)
 class DenseMLP(_Composed):
-    """Uniform-width MLP; params are the seed :class:`MLPParams` NamedTuple,
-    so everything that holds an ``MLPParams`` works unchanged -- the stacked
-    pytree is adapted onto a Sequential of Dense leaves at call time."""
+    """Uniform-width MLP; params are the stacked :class:`MLPParams`
+    NamedTuple, adapted onto a Sequential of Dense leaves at call time for
+    the jet forward."""
 
     d_in: int
     width: int
     depth: int
     d_out: int
     activation: str = "tanh"
-
-    @classmethod
-    def from_params(cls, params: MLPParams, activation: str = "tanh") -> "DenseMLP":
-        """Recover the architecture from a parameter pytree (for call sites
-        that hold only the seed NamedTuple, e.g. the legacy ntp_grid/cross
-        wrappers in core/ntp.py)."""
-        return cls(d_in=params.w_in.shape[0], width=params.w_in.shape[1],
-                   depth=params.w_hidden.shape[0] + 1,
-                   d_out=params.w_out.shape[1], activation=activation)
 
     def init(self, key: jax.Array, dtype=jnp.float32) -> MLPParams:
         return init_mlp(key, self.d_in, self.width, self.depth, self.d_out,
@@ -157,7 +150,25 @@ class DenseMLP(_Composed):
         # the stacked pytree admits a lax.scan over hidden layers, keeping
         # the primal forward's compile time O(1) in depth; unroll=True (for
         # jax.experimental.jet, which has no scan rule) python-unrolls
-        return mlp_apply(params, x, self.activation, unroll=unroll)
+        act = PRIMALS[self.activation]
+
+        def mm(a, w):
+            return jnp.matmul(a, w, precision=J.MATMUL_PRECISION)
+
+        h = act(mm(x, params.w_in) + params.b_in)
+
+        if unroll:
+            for i in range(params.w_hidden.shape[0]):
+                h = act(mm(h, params.w_hidden[i]) + params.b_hidden[i])
+            return mm(h, params.w_out) + params.b_out
+
+        def body(h, wb):
+            w, b = wb
+            return act(mm(h, w) + b), None
+
+        if params.w_hidden.shape[0]:
+            h, _ = jax.lax.scan(body, h, (params.w_hidden, params.b_hidden))
+        return mm(h, params.w_out) + params.b_out
 
 
 # ---------------------------------------------------------------------------

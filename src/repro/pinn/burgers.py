@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import jet as J
-from repro.core.ntp import MLPParams, mlp_apply, ntp_forward
+from repro.core.network import Network
 
 
 def profile_lambda(k: int) -> float:
@@ -77,13 +77,14 @@ def jet_derivative(j: J.Jet) -> J.Jet:
     return J.Jet(j.coeffs[1:] * ks.reshape((-1,) + (1,) * len(j.shape)))
 
 
-def residual_jet(params: MLPParams, lam, x: jnp.ndarray, order: int,
-                 activation: str = "tanh", impl: str = "jnp") -> J.Jet:
+def residual_jet(net: Network, params, lam, x: jnp.ndarray, order: int,
+                 impl: str = "jnp") -> J.Jet:
     """Jet of R along X at each collocation point; R-jet order = ``order``.
 
-    Needs the u-jet to order+1 (R contains U').  One n-TangentProp pass."""
-    u = ntp_forward(params, x, order + 1, activation=activation,
-                    impl=impl)                             # (order+2, N, 1)
+    Needs the u-jet to order+1 (R contains U').  One n-TangentProp pass
+    (``net.jet_apply`` under ``impl``)."""
+    u = net.jet_apply(params, J.seed(x, None, order + 1),
+                      impl=impl)                           # (order+2, N, 1)
     up = jet_derivative(u)                                 # order+1
     u = J.Jet(u.coeffs[:order + 1])                        # truncate to order
     up = J.Jet(up.coeffs[:order + 1])
@@ -92,15 +93,15 @@ def residual_jet(params: MLPParams, lam, x: jnp.ndarray, order: int,
     return J.add(J.scale(u, -lam), J.mul(adv, up))
 
 
-def residual_derivs_autodiff(params: MLPParams, lam, x: jnp.ndarray,
-                             order: int, activation: str = "tanh") -> jnp.ndarray:
+def residual_derivs_autodiff(net: Network, params, lam, x: jnp.ndarray,
+                             order: int) -> jnp.ndarray:
     """Baseline: same quantities via nested autodiff (O(M^n) graph).
 
     Returns (order+1, N, 1) raw derivatives of R, matching
     J.derivatives(residual_jet(...))."""
 
     def u_fn(xs):
-        return mlp_apply(params, xs[None, :], activation, unroll=True)[0, 0]
+        return net.apply(params, xs[None, :], unroll=True)[0, 0]
 
     def r_fn(xs):
         u = u_fn(xs)

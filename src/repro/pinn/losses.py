@@ -23,9 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import jet as J
-from repro.core.engines import DerivativeEngine
+from repro.core.engines import AutodiffEngine, DerivativeEngine, NTPEngine
 from repro.core.network import Network, token_points
-from repro.core.ntp import MLPParams, mlp_apply
 from repro.runtime.metrics import scope
 
 from .burgers import exact_profile, residual_derivs_autodiff, residual_jet
@@ -96,20 +95,6 @@ def pinn_loss(params, *, op: Union[Operator, str], pts: jnp.ndarray,
 # the self-similar Burgers objective (paper section IV-C)
 # ---------------------------------------------------------------------------
 
-def _burgers_engine(engine: Union[str, DerivativeEngine]) -> Tuple[str, str]:
-    """The specialized Burgers jet pipeline predates the engine objects;
-    normalize a spec string or engine instance back to its
-    ("ntp"|"autodiff", impl) string pair."""
-    from repro.core.engines import AutodiffEngine, DerivativeEngine, NTPEngine
-    eng = DerivativeEngine.from_spec(engine)
-    if isinstance(eng, NTPEngine):
-        return "ntp", eng.impl
-    if isinstance(eng, AutodiffEngine):
-        return "autodiff", "jnp"
-    raise ValueError(f"burgers objective supports the ntp and autodiff "
-                     f"engines, not {eng.spec!r}")
-
-
 def bc_targets(k: int, domain: float) -> Tuple[float, float]:
     """U_true(+-L) with the C=1 normalization."""
     import numpy as np
@@ -117,30 +102,36 @@ def bc_targets(k: int, domain: float) -> Tuple[float, float]:
     return float(vals[0]), float(vals[1])
 
 
-def burgers_pinn_loss(params: MLPParams, lam_raw: jnp.ndarray, *, k: int,
+def burgers_pinn_loss(params, lam_raw: jnp.ndarray, *, net: Network, k: int,
                       pts: jnp.ndarray, origin_pts: jnp.ndarray, domain: float,
                       order: int, weights: LossWeights,
-                      lam_window: Tuple[float, float], engine: str = "ntp",
-                      activation: str = "tanh",
+                      lam_window: Tuple[float, float],
+                      engine: Union[str, DerivativeEngine] = "ntp",
                       bc_vals: Tuple[float, float] = None) -> Tuple[jnp.ndarray, Dict]:
-    """Full self-similar Burgers objective.  ``engine``: a spec string
-    ("ntp", "ntp/pallas", "autodiff") or :class:`DerivativeEngine` instance.
-    Everything else is identical, so the benchmark isolates the derivative
-    engine."""
-    engine, impl = _burgers_engine(engine)
+    """Full self-similar Burgers objective on the scalar network ``net``.
+    ``engine``: a spec string ("ntp", "ntp/pallas", "autodiff") or
+    :class:`DerivativeEngine` instance; the ntp engines push one jet per
+    point set through ``net.jet_apply``, the autodiff engine nests
+    ``jax.grad`` over ``net.apply``.  Everything else is identical, so the
+    benchmark isolates the derivative engine."""
     lo, hi = lam_window
     lam = lo + (hi - lo) * jax.nn.sigmoid(lam_raw)
-
-    if engine == "ntp":
-        # one jet to order 1 on the full domain (residual + Sobolev-1) ...
-        r_dom = J.derivatives(residual_jet(params, lam, pts, 1,
-                                           activation=activation, impl=impl))
-        # ... and one high-order jet on the origin cluster
-        r_org = J.derivatives(residual_jet(params, lam, origin_pts, order,
-                                           activation=activation, impl=impl))
+    eng = DerivativeEngine.from_spec(engine)
+    if isinstance(eng, NTPEngine):
+        def r_derivs(x, n):
+            return J.derivatives(residual_jet(net, params, lam, x, n,
+                                              impl=eng.impl))
+    elif isinstance(eng, AutodiffEngine):
+        def r_derivs(x, n):
+            return residual_derivs_autodiff(net, params, lam, x, n)
     else:
-        r_dom = residual_derivs_autodiff(params, lam, pts, 1, activation)
-        r_org = residual_derivs_autodiff(params, lam, origin_pts, order, activation)
+        raise ValueError(f"burgers objective supports the ntp and autodiff "
+                         f"engines, not {eng.spec!r}")
+
+    # one order-1 table on the full domain (residual + Sobolev-1) and one
+    # high-order table on the origin cluster
+    r_dom = r_derivs(pts, 1)
+    r_org = r_derivs(origin_pts, order)
 
     l_res = jnp.mean(r_dom[0] ** 2)
     l_sob = jnp.mean(r_dom[1] ** 2)
@@ -148,11 +139,10 @@ def burgers_pinn_loss(params: MLPParams, lam_raw: jnp.ndarray, *, k: int,
 
     # boundary conditions: U(0)=0, U'(0)=-1, U(+-L) pinned to the C=1 profile
     x0 = jnp.zeros((1, 1), pts.dtype)
-    u0j = J.derivatives(residual_jet_u(params, x0, activation=activation,
-                                       impl=impl))
+    u0j = eng.derivs(net, params, x0, 1)
     u0, du0 = u0j[0, 0, 0], u0j[1, 0, 0]
     xb = jnp.asarray([[-domain], [domain]], pts.dtype)
-    ub = mlp_apply(params, xb, activation)
+    ub = net.apply(params, xb)
     tb = jnp.asarray(bc_vals, pts.dtype)
     l_bc = u0 ** 2 + (du0 + 1.0) ** 2 + jnp.mean((ub[:, 0] - tb) ** 2)
 
@@ -160,10 +150,3 @@ def burgers_pinn_loss(params: MLPParams, lam_raw: jnp.ndarray, *, k: int,
             weights.origin * l_org + weights.bc * l_bc)
     return loss, {"residual": l_res, "sobolev1": l_sob, "origin": l_org,
                   "bc": l_bc, "lambda": lam}
-
-
-def residual_jet_u(params: MLPParams, x: jnp.ndarray, activation: str = "tanh",
-                   impl: str = "jnp") -> J.Jet:
-    """Order-1 jet of U itself (for the U(0), U'(0) boundary terms)."""
-    from repro.core.ntp import ntp_forward
-    return ntp_forward(params, x, 1, activation=activation, impl=impl)

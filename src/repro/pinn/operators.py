@@ -63,8 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.engines import DerivativeEngine, table_engine
-from repro.core.network import DenseMLP, Network, token_points
-from repro.core.ntp import MLPParams
+from repro.core.network import Network, token_points
 
 
 class DerivTable:
@@ -304,15 +303,6 @@ def residual_of_fn(op: Operator, fn: Callable[[jnp.ndarray], jnp.ndarray],
     return op.residual(x, DerivTable(pure, mixed))
 
 
-def ntp_pure_derivs(params: MLPParams, x: jnp.ndarray, order: int,
-                    activation: str = "tanh", impl: str = "jnp") -> jnp.ndarray:
-    """(d_in, order+1, N) raw pure derivatives of the network, one jet batch.
-    (Legacy surface; ``engine.grid(net, ...)`` is the generic form.)"""
-    from repro.core.engines import NTPEngine
-    net = DenseMLP.from_params(params, activation)
-    return NTPEngine(impl).grid(net, params, x, order)[..., 0]
-
-
 # ---------------------------------------------------------------------------
 # registered operators (coefficients chosen so no term degenerates)
 # ---------------------------------------------------------------------------
@@ -421,7 +411,7 @@ register(Operator(
 # rotation R(theta) and principal diffusivities V = diag(nu1, nu2).  In the
 # unrotated frame div(D grad u) = d11 u_xx + 2 d12 u_xy + d22 u_yy, so the
 # residual has a *genuine mixed-partial term* -- the first registered
-# operator to consume polarization (engine.cross / repro.core.ntp.cross).
+# operator to consume polarization (engine.cross).
 
 AD_THETA = _PI / 6.0
 AD_NU = (0.3, 0.1)

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from repro.core.engines import DerivativeEngine
 from repro.core.jet import float_dtype
 from repro.core.network import Network, make_network, token_points
-from repro.core.ntp import MLPParams, init_mlp, num_params
+from repro.core.ntp import MLPParams, num_params
 from repro.data.collocation import (boundary_grid, eval_grid, resample,
                                     sample_box, uniform_grid)
 from repro.optim import adam_init, adam_update, lbfgs
@@ -62,22 +62,20 @@ class PINNResult:
     lbfgs_time_s: float
     n_params: int
     order: int
+    target_lam: float               # lam of the profile the run was set for
 
     @property
     def lam_error(self) -> float:
-        return abs(self.lam - profile_lambda_from_history(self))
-
-
-def profile_lambda_from_history(res: "PINNResult") -> float:
-    # target lam for the profile this run was configured for
-    return res._target_lam  # set by train()
+        return abs(self.lam - self.target_lam)
 
 
 def train(cfg: PINNRunConfig) -> PINNResult:
     dtype = float_dtype()
     key = jax.random.PRNGKey(cfg.seed)
     k_init, k_pts = jax.random.split(key)
-    params = init_mlp(k_init, 1, cfg.width, cfg.depth, 1, dtype=dtype)
+    net = make_network("dense", d_in=1, d_out=1, width=cfg.width,
+                       depth=cfg.depth, activation=cfg.activation)
+    params = net.init(k_init, dtype=dtype)
     lam_raw = jnp.zeros((), dtype)
     order = smoothness_order(cfg.k)
     window = lambda_window(cfg.k)
@@ -85,11 +83,11 @@ def train(cfg: PINNRunConfig) -> PINNResult:
 
     def loss_fn(ps, pts, origin_pts):
         p, lr = ps
-        return burgers_pinn_loss(p, lr, k=cfg.k, pts=pts, origin_pts=origin_pts,
-                                 domain=cfg.domain, order=order,
-                                 weights=cfg.weights, lam_window=window,
-                                 engine=cfg.engine,
-                                 activation=cfg.activation, bc_vals=bc_vals)
+        return burgers_pinn_loss(p, lr, net=net, k=cfg.k, pts=pts,
+                                 origin_pts=origin_pts, domain=cfg.domain,
+                                 order=order, weights=cfg.weights,
+                                 lam_window=window, engine=cfg.engine,
+                                 bc_vals=bc_vals)
 
     # ---------------- Adam phase
     state = adam_init((params, lam_raw))
@@ -141,12 +139,11 @@ def train(cfg: PINNRunConfig) -> PINNResult:
 
     params, lam_raw = res.params
     lam = float(_lam_of(lam_raw, window))
-    out = PINNResult(params=params, lam=lam, lam_history=lam_hist,
-                     loss_history=loss_hist + res.loss_history,
-                     adam_time_s=adam_time, lbfgs_time_s=lbfgs_time,
-                     n_params=num_params(params), order=order)
-    out._target_lam = profile_lambda(cfg.k)
-    return out
+    return PINNResult(params=params, lam=lam, lam_history=lam_hist,
+                      loss_history=loss_hist + res.loss_history,
+                      adam_time_s=adam_time, lbfgs_time_s=lbfgs_time,
+                      n_params=num_params(params), order=order,
+                      target_lam=profile_lambda(cfg.k))
 
 
 def _lam_of(lam_raw, window):

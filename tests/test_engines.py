@@ -212,11 +212,31 @@ def test_engine_spec_direct_constructor_validates():
 def test_legacy_shim_is_gone():
     """ROADMAP scheduled the PR-2 deprecation shim for removal: the
     engine=/impl= keyword pair and the bare-MLPParams reconstruction no
-    longer exist anywhere on the public surface."""
+    longer exist anywhere on the public surface.  Nor does the seed's
+    second dense-MLP path: its jet forward, plain forward and wrappers
+    (``DenseMLP`` and the engines are the one path), its oracle module
+    (``AutodiffEngine`` / ``JaxJetEngine`` are the oracles), and the Burgers
+    objective's string-pair shim."""
+    import importlib.util
+
     import repro.core as core
+    import repro.core.ntp as ntp
     import repro.pinn as pinn
+    import repro.pinn.losses as losses
+    import repro.pinn.operators as operators
+    import repro.pinn.trainer as trainer
     assert not hasattr(core, "resolve_engine")
     assert not hasattr(pinn, "resolve_net_engine")
+    for name in ("ntp_jet", "ntp_forward", "ntp_derivatives", "ntp_grid",
+                 "cross", "mlp_apply", "_dense_view"):
+        assert not hasattr(core, name) and not hasattr(ntp, name), name
+    assert importlib.util.find_spec("repro.core.baselines") is None
+    assert not hasattr(DenseMLP, "from_params")
+    assert not hasattr(pinn, "ntp_pure_derivs")
+    assert not hasattr(operators, "ntp_pure_derivs")
+    assert not hasattr(losses, "_burgers_engine")
+    assert not hasattr(trainer, "profile_lambda_from_history")
+    assert "target_lam" in trainer.PINNResult.__dataclass_fields__
     op = get_operator("heat")
     params = init_mlp(jax.random.PRNGKey(0), 2, 10, 2, 1, dtype=jnp.float64)
     x = sample_box(jax.random.PRNGKey(1), op.domain, 8, jnp.float64)

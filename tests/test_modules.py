@@ -15,8 +15,9 @@ from repro.core import (DenseMLP, FourierFeatureMLP, MLP, ResidualMLP,
 from repro.core.modules import (Activation, CoordinateEmbedding, Dense,
                                 FourierFeatures, MLPBlock, RMSNorm, Residual,
                                 SelfAttention, Sequential, TokenPool,
-                                make_module, module_names, register_module)
-from repro.core.ntp import init_mlp, xavier_uniform
+                                make_module, module_names, register_module,
+                                xavier_uniform)
+from repro.core.ntp import init_mlp
 from repro.kernels import ops as kops
 
 
@@ -209,6 +210,14 @@ def test_dense_mlp_params_unchanged_by_module_refactor():
     for a, b in zip(jax.tree_util.tree_leaves(p),
                     jax.tree_util.tree_leaves(ref)):
         np.testing.assert_array_equal(a, b)
+    # init_mlp draws every weight as the leaves' xavier_uniform does
+    ks = jax.random.split(key, 4)
+    np.testing.assert_array_equal(
+        p.w_in, xavier_uniform(ks[0], 2, 10, jnp.float64))
+    np.testing.assert_array_equal(
+        p.w_hidden[1], xavier_uniform(ks[2], 10, 10, jnp.float64))
+    np.testing.assert_array_equal(
+        p.w_out, xavier_uniform(ks[3], 10, 1, jnp.float64))
 
 
 def test_mlp_params_unchanged_by_module_refactor():

@@ -12,7 +12,7 @@ import pytest
 from repro.core import jet as J
 from repro.core.engines import AutodiffEngine, DerivativeEngine, NTPEngine
 from repro.core.network import DenseMLP
-from repro.core.ntp import cross, init_mlp, mlp_apply
+from repro.core.ntp import init_mlp
 from repro.data.collocation import boundary_grid, eval_grid, sample_box
 from repro.pinn import (DerivTable, LossWeights, OperatorRunConfig,
                         autodiff_mixed_partial_fn, burgers_operator,
@@ -123,7 +123,7 @@ def test_burgers_operator_matches_residual_jet():
     Burgers jet pipeline (losses.burgers_pinn_loss's engine)."""
     op, net, params, x = _net_and_pts("burgers")
     ours = residual_values(params, op, x, net=net, engine="ntp")
-    ref = J.derivatives(residual_jet(params, 0.5, x, 1))[0, :, 0]
+    ref = J.derivatives(residual_jet(net, params, 0.5, x, 1))[0, :, 0]
     np.testing.assert_allclose(ours, ref, rtol=1e-10, atol=1e-12)
 
 
@@ -138,7 +138,7 @@ def test_navier_stokes_consumes_4th_order_polarization():
     op, net, params, x = _net_and_pts("navier-stokes", n=5, width=10, depth=2)
     eng = NTPEngine("jnp")
     ours = eng.cross(net, params, x, (0, 0, 1, 1))[:, 0]
-    fn = lambda xi: mlp_apply(params, xi[None, :], unroll=True)[0, 0]
+    fn = lambda xi: net.apply(params, xi[None, :], unroll=True)[0, 0]
     ref = autodiff_mixed_partial_fn(fn, x, (0, 0, 1, 1))
     np.testing.assert_allclose(ours, ref, rtol=1e-7, atol=1e-8)
 
@@ -226,7 +226,7 @@ def test_advection_diffusion_consumes_cross_polarization():
     of directional jets) and matches a direct nested-grad mixed partial."""
     op, net, params, x = _net_and_pts("advection-diffusion")
     ours = NTPEngine("jnp").cross(net, params, x, (1, 2))[:, 0]
-    fn = lambda xi: mlp_apply(params, xi[None, :], unroll=True)[0, 0]
+    fn = lambda xi: net.apply(params, xi[None, :], unroll=True)[0, 0]
     ref = autodiff_mixed_partial_fn(fn, x, (1, 2))
     np.testing.assert_allclose(ours, ref, rtol=1e-8, atol=1e-10)
     # and the mixed term genuinely contributes to the residual (d12 != 0)
@@ -293,9 +293,11 @@ def test_deriv_table_surface():
 # ---------------------------------------------------------------------------
 
 def test_cross_polarization_matches_autodiff():
+    net = DenseMLP(2, 14, 3, 1)
     params = init_mlp(jax.random.PRNGKey(4), 2, 14, 3, 1, dtype=jnp.float64)
     x = jax.random.normal(jax.random.PRNGKey(5), (6, 2), jnp.float64)
-    fn = lambda xi: mlp_apply(params, xi[None, :], unroll=True)[0, 0]
+    fn = lambda xi: net.apply(params, xi[None, :], unroll=True)[0, 0]
+    cross = lambda p, xx, axes: NTPEngine().cross(net, p, xx, axes)
 
     H = jax.vmap(jax.hessian(fn))(x)                        # (N, 2, 2)
     np.testing.assert_allclose(cross(params, x, (0, 1))[:, 0], H[:, 0, 1],
@@ -310,7 +312,9 @@ def test_cross_polarization_matches_autodiff():
 
 
 def test_cross_symmetry_of_mixed_partials():
+    net = DenseMLP(3, 10, 2, 1)
     params = init_mlp(jax.random.PRNGKey(6), 3, 10, 2, 1, dtype=jnp.float64)
+    cross = lambda p, xx, axes: NTPEngine().cross(net, p, xx, axes)
     x = jax.random.normal(jax.random.PRNGKey(7), (4, 3), jnp.float64)
     np.testing.assert_allclose(cross(params, x, (0, 2)), cross(params, x, (2, 0)),
                                rtol=1e-9, atol=1e-11)

@@ -5,8 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import DenseMLP
 from repro.core import jet as J
-from repro.core.ntp import init_mlp
 from repro.pinn import (PINNRunConfig, exact_profile, lambda_window,
                         profile_lambda, residual_derivs_autodiff, residual_jet,
                         smoothness_order, train)
@@ -33,10 +33,11 @@ def test_exact_profile_roundtrip():
     # O(M^n) blowup the paper removes) -- keep it, but out of tier-1
     pytest.param(7, marks=pytest.mark.slow)])
 def test_residual_jet_matches_autodiff(order):
-    params = init_mlp(jax.random.PRNGKey(0), 1, 24, 3, 1, dtype=jnp.float64)
+    net = DenseMLP(1, 24, 3, 1)
+    params = net.init(jax.random.PRNGKey(0), dtype=jnp.float64)
     x = jnp.linspace(-1, 1, 7, dtype=jnp.float64)[:, None]
-    ours = J.derivatives(residual_jet(params, 0.5, x, order))
-    ref = residual_derivs_autodiff(params, 0.5, x, order)
+    ours = J.derivatives(residual_jet(net, params, 0.5, x, order))
+    ref = residual_derivs_autodiff(net, params, 0.5, x, order)
     np.testing.assert_allclose(ours, ref, rtol=1e-8, atol=1e-10)
 
 
@@ -66,15 +67,18 @@ def test_engines_share_loss_surface():
     """ntp and autodiff engines compute the same loss (paper: exact method)."""
     from repro.pinn.losses import LossWeights, bc_targets, burgers_pinn_loss
 
-    params = init_mlp(jax.random.PRNGKey(0), 1, 16, 2, 1, dtype=jnp.float64)
+    net = DenseMLP(1, 16, 2, 1)
+    params = net.init(jax.random.PRNGKey(0), dtype=jnp.float64)
     pts = jnp.linspace(-1, 1, 16, dtype=jnp.float64)[:, None]
     opts = jnp.linspace(-0.1, 0.1, 8, dtype=jnp.float64)[:, None]
-    kw = dict(k=1, pts=pts, origin_pts=opts, domain=1.0, order=3,
+    kw = dict(net=net, k=1, pts=pts, origin_pts=opts, domain=1.0, order=3,
               weights=LossWeights(), lam_window=(1 / 3, 1.0),
               bc_vals=bc_targets(1, 1.0))
     l1, _ = burgers_pinn_loss(params, jnp.zeros(()), engine="ntp", **kw)
     l2, _ = burgers_pinn_loss(params, jnp.zeros(()), engine="autodiff", **kw)
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-9)
+    with pytest.raises(ValueError, match="ntp and autodiff"):
+        burgers_pinn_loss(params, jnp.zeros(()), engine="jet", **kw)
 
 
 def test_burgers_loss_respects_activation():
@@ -82,17 +86,18 @@ def test_burgers_loss_respects_activation():
     configured activation; a sin-activated net must yield a different loss."""
     from repro.pinn.losses import LossWeights, bc_targets, burgers_pinn_loss
 
-    params = init_mlp(jax.random.PRNGKey(3), 1, 16, 2, 1, dtype=jnp.float64)
+    tanh_net, sin_net = DenseMLP(1, 16, 2, 1), DenseMLP(1, 16, 2, 1, "sin")
+    params = tanh_net.init(jax.random.PRNGKey(3), dtype=jnp.float64)
     pts = jnp.linspace(-1, 1, 16, dtype=jnp.float64)[:, None]
     opts = jnp.linspace(-0.1, 0.1, 8, dtype=jnp.float64)[:, None]
     kw = dict(k=1, pts=pts, origin_pts=opts, domain=1.0, order=3,
               weights=LossWeights(), lam_window=(1 / 3, 1.0),
               bc_vals=bc_targets(1, 1.0))
-    l_tanh, _ = burgers_pinn_loss(params, jnp.zeros(()), activation="tanh", **kw)
-    l_sin, _ = burgers_pinn_loss(params, jnp.zeros(()), activation="sin", **kw)
+    l_tanh, _ = burgers_pinn_loss(params, jnp.zeros(()), net=tanh_net, **kw)
+    l_sin, _ = burgers_pinn_loss(params, jnp.zeros(()), net=sin_net, **kw)
     assert not np.isclose(float(l_tanh), float(l_sin))
     # and the sin-activated loss agrees across engines (activation threaded
     # consistently through every term, boundary included)
-    l_sin_ad, _ = burgers_pinn_loss(params, jnp.zeros(()), activation="sin",
+    l_sin_ad, _ = burgers_pinn_loss(params, jnp.zeros(()), net=sin_net,
                                     engine="autodiff", **kw)
     np.testing.assert_allclose(float(l_sin), float(l_sin_ad), rtol=1e-9)

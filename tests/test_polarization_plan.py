@@ -22,7 +22,6 @@ import pytest
 from repro.core.engines import (AutodiffEngine, NTPEngine, PolarizationPlan,
                                 axis_directions)
 from repro.core.network import DenseMLP
-from repro.core.ntp import init_mlp
 from repro.data.collocation import sample_box
 from repro.pinn.operators import (Operator, autodiff_mixed_partial_fn,
                                   build_table, get_operator)
@@ -55,8 +54,7 @@ def _op(name):
 
 def _net_and_pts(op, dtype, width=10, depth=3, n=7):
     net = DenseMLP(op.d_in, width, depth, op.d_out)
-    params = init_mlp(jax.random.PRNGKey(0), op.d_in, width, depth, op.d_out,
-                      dtype=dtype)
+    params = net.init(jax.random.PRNGKey(0), dtype=dtype)
     x = sample_box(jax.random.PRNGKey(1), op.domain, n, dtype)
     return net, params, x
 
