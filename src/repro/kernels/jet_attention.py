@@ -34,6 +34,7 @@ and the output casts back to the input dtype.
 from __future__ import annotations
 
 import functools
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -42,10 +43,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.jet import MATMUL_PRECISION
 
+from .wave_jet import SUB_ROWS, over_chunks
+
 # Finite "minus infinity" for masked score positions: large enough that exp
 # underflows to exactly 0, small enough that (NEG - NEG) stays 0.0 and no
 # inf/NaN can enter the jet recurrences (a true -inf would produce inf-inf).
 MASK_NEG = -1e30
+
+# The flash-jet key tile: a sequence no longer than this is one KV block, so
+# its backward runs as the one-tile kernel ``flash_jet_bwd``
+FLASH_BLOCK_K = 64
 
 
 def attention_scores_jet_body(q: jnp.ndarray, k: jnp.ndarray,
@@ -293,7 +300,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, wo_ref, o_ref, m_ref, t_ref, a_ref, *,
 def jet_flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                                wo: jnp.ndarray, scale: float,
                                mask: str = "none", window: int = 0,
-                               block_q: int = 64, block_k: int = 64,
+                               block_q: int = 64,
+                               block_k: int = FLASH_BLOCK_K,
                                block_b: int = 8,
                                interpret: bool = True) -> jnp.ndarray:
     """Tiled flash-jet attention: Q/K/V coefficient stacks (n+1, B, H, T, Dh)
@@ -353,6 +361,188 @@ def jet_flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         name="jet_flash_attention",
     )(qp, kp, vp, wo)
     return out[:, :bsz, :t]
+
+
+# ---------------------------------------------------------------------------
+# The flash-jet backward for a sequence that fits one key tile (T <= block_k):
+# per batch block, recompute the forward's jets in VMEM as _flash_kernel
+# spells them, with no online rescaling (one tile, so the running max is the
+# row max and alpha is 1), then run the closed-form reverse of each step in
+# the opposite order:
+#
+#   out_m = sum_h o_m[h] wo[h]         ->  do_m = g_m wo^T,  dwo += o_m^T g_m
+#   a = t (*) o   (jet division)       ->  da, dt
+#   a_m = sum_i e_i v_{m-i}            ->  de, dv
+#   t_m = sum_keys e_m                 ->  de += dt
+#   e_m = (1/m) sum_j j s_j e_{m-j}    ->  ds, and ds_0 = de_0 e_0
+#   s_m = scale sum_i q_i k_{m-i}^T    ->  dq, dk
+#
+# The row max only shifts e_0 by a t-constant factor that the division
+# cancels, so no gradient flows through it: dropping it is exact.  Masked
+# keys keep e-jets of exactly 0, so every ds term there carries a 0 factor.
+# ---------------------------------------------------------------------------
+
+
+def _total(terms):
+    return functools.reduce(operator.add, terms)
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, wo_ref, g_ref,
+                      dq_ref, dk_ref, dv_ref, dwo_ref, *,
+                      scale, mask, window, rows, sub):
+    n1, bb, n_heads, t = q_ref.shape[:4]
+    acc_t = dwo_ref.dtype
+    neg = jnp.asarray(MASK_NEG, acc_t)
+    keep = _flash_block_keep(mask, window, 0, 0, t, t, t)[None]
+
+    def mm(x, y, cx: int, cy: int) -> jnp.ndarray:
+        # one contraction batched over the rows alone, as in the forward
+        return jax.lax.dot_general(
+            x, y, dimension_numbers=(((cx,), (cy,)), ((0,), (0,))),
+            precision=MATMUL_PRECISION, preferred_element_type=acc_t)
+
+    def cauchy(x, y, cx: int, cy: int) -> list:
+        # [sum_{i+j=m} x_i . y_j for m = 0..n]
+        return [_total(mm(x[i], y[m - i], cx, cy) for i in range(m + 1))
+                for m in range(n1)]
+
+    def transposed(dx, y, cx: int, cy: int) -> list:
+        # [sum_{m >= j} dx_m . y_{m-j} for j = 0..n]: cauchy's reverse in x
+        return [_total(mm(dx[m], y[m - j], cx, cy) for m in range(j, n1))
+                for j in range(n1)]
+
+    def head(r, h, g):
+        q = [q_ref[m, r, h].astype(acc_t) for m in range(n1)]  # (sub, T, D)
+        k = [k_ref[m, r, h].astype(acc_t) for m in range(n1)]
+        v = [v_ref[m, r, h].astype(acc_t) for m in range(n1)]
+
+        # recompute: scores, exp jet, totals, value jet, o by jet division
+        s = [x * scale for x in cauchy(q, k, 2, 2)]           # (sub, T, T)
+        s0m = jnp.where(keep, s[0], neg)
+        e = [jnp.where(keep, jnp.exp(
+            s0m - jnp.max(s0m, axis=-1, keepdims=True)), 0.0)]
+        for m in range(1, n1):
+            acc = m * s[m] * e[0]
+            for j in range(1, m):
+                acc = acc + j * s[j] * e[m - j]
+            e.append(acc / m)
+        tot = [jnp.sum(em, axis=-1, keepdims=True) for em in e]  # (sub, T, 1)
+        a = cauchy(e, v, 2, 1)                                # (sub, T, D)
+        inv0 = 1.0 / jnp.maximum(tot[0], jnp.asarray(1e-37, acc_t))
+        o = [a[0] * inv0]
+        for m in range(1, n1):
+            acc = a[m]
+            for j in range(1, m + 1):
+                acc = acc - tot[j] * o[m - j]
+            o.append(acc * inv0)
+
+        # the output projection: do_m = g_m wo[h]^T, dwo[h] = sum o_m^T g_m
+        wo_h = jnp.broadcast_to(wo_ref[h].astype(acc_t),
+                                (g[0].shape[0],) + wo_ref.shape[1:])
+        do = [mm(g[m], wo_h, 2, 2) for m in range(n1)]
+        dwo = _total(mm(o[m], g[m], 1, 1) for m in range(n1))  # (sub, D, Dm)
+
+        # the jet division o_m = (a_m - sum_{j>=1} t_j o_{m-j}) / t_0
+        da, dtot = [None] * n1, [None] * n1
+        for m in reversed(range(n1)):
+            r_m = do[m] * inv0
+            da[m] = r_m
+            for j in range(m + 1):
+                d = -jnp.sum(r_m * o[m - j], axis=-1, keepdims=True)
+                dtot[j] = d if dtot[j] is None else dtot[j] + d
+                if j:
+                    do[m - j] = do[m - j] - tot[j] * r_m
+
+        # the value product, then the totals
+        dv = [_total(mm(e[m - j], da[m], 1, 1) for m in range(j, n1))
+              for j in range(n1)]                             # (sub, T, D)
+        de = [x + dt for x, dt in zip(transposed(da, v, 2, 2), dtot)]
+
+        # the exp recurrence, highest order first
+        ds = [None] * n1
+        for m in range(n1 - 1, 0, -1):
+            u = de[m] / m
+            for j in range(1, m + 1):
+                d = j * u * e[m - j]
+                ds[j] = d if ds[j] is None else ds[j] + d
+                de[m - j] = de[m - j] + j * u * s[j]
+        ds[0] = de[0] * e[0]
+        ds = [x * scale for x in ds]
+
+        # the score product
+        dq = transposed(ds, k, 2, 1)
+        dk = transposed(ds, q, 1, 1)
+        for m in range(n1):
+            dq_ref[m, r, h] = dq[m].astype(dq_ref.dtype)
+            dk_ref[m, r, h] = dk[m].astype(dk_ref.dtype)
+            dv_ref[m, r, h] = dv[m].astype(dv_ref.dtype)
+        return dwo
+
+    first = pl.program_id(0) * bb
+
+    def chunk(r, sums):
+        g = [g_ref[m, r].astype(acc_t) for m in range(n1)]     # (sub, T, Dm)
+        out = []
+        for h in range(n_heads):
+            dwo = head(r, h, g)
+            if rows % bb:
+                # the ragged last block reads past the batch's end: keep
+                # those rows out of the weight sum
+                row = first + r.start + jax.lax.broadcasted_iota(
+                    jnp.int32, dwo.shape, 0)
+                dwo = jnp.where(row < rows, dwo, 0.0)
+            out.append(sums[h] + dwo)
+        return tuple(out)
+
+    n = bb if sub >= bb or bb % sub else sub
+    zero = jnp.zeros((n,) + dwo_ref.shape[2:], acc_t)
+    sums = over_chunks(bb, sub, chunk, (zero,) * n_heads)
+    for h in range(n_heads):
+        dwo_ref[0, h] = jnp.sum(sums[h], axis=0).astype(dwo_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "scale", "mask", "window", "block_b", "interpret"))
+def flash_jet_bwd_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                         wo: jnp.ndarray, g: jnp.ndarray, scale: float,
+                         mask: str = "none", window: int = 0,
+                         block_b: int = 16, interpret: bool = True):
+    """The VJP of :func:`jet_flash_attention_pallas` at ``(q, k, v, wo)``
+    against the output cotangent ``g`` (n+1, B, T, Dm), for a sequence that
+    fits one key tile (T <= ``FLASH_BLOCK_K``): ``(dq, dk, dv, dwo)``, one
+    launch over batch blocks, ``dwo`` summed from one (H, Dh, Dm) partial
+    per block.  A ragged last block's rows past B are dropped on write and
+    kept out of ``dwo``."""
+    n1, bsz, h, t, d = q.shape
+    if t > FLASH_BLOCK_K:
+        raise ValueError(f"{t} tokens do not fit one key tile of "
+                         f"{FLASH_BLOCK_K}")
+    dm = wo.shape[2]
+    if g.shape != (n1, bsz, t, dm):
+        raise ValueError(f"cotangent shape {g.shape} != "
+                         f"{(n1, bsz, t, dm)}")
+    # the working set grows with bb * T^2: long sequences take fewer rows
+    bb = min(block_b, bsz, max(1, block_b * 8 // max(t, 8)))
+    grid = pl.cdiv(bsz, bb)
+    acc_t = jnp.promote_types(q.dtype, jnp.float32)
+    stack = pl.BlockSpec((n1, bb, h, t, d), lambda i: (0, i, 0, 0, 0))
+    dq, dk, dv, parts = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=scale, mask=mask,
+                          window=window, rows=bsz, sub=SUB_ROWS),
+        grid=(grid,),
+        in_specs=[stack, stack, stack,
+                  pl.BlockSpec((h, d, dm), lambda i: (0, 0, 0)),
+                  pl.BlockSpec((n1, bb, t, dm), lambda i: (0, i, 0, 0))],
+        out_specs=[stack, stack, stack,
+                   pl.BlockSpec((1, h, d, dm), lambda i: (i, 0, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] * 3
+        + [jax.ShapeDtypeStruct((grid, h, d, dm), acc_t)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="flash_jet_bwd",
+    )(q, k, v, wo, g.astype(q.dtype))
+    return dq, dk, dv, jnp.sum(parts, axis=0).astype(wo.dtype)
 
 
 def rms_norm_jet_body(x: jnp.ndarray, gamma: jnp.ndarray,

@@ -37,10 +37,11 @@ from typing import Mapping
 import jax
 import jax.numpy as jnp
 
-from repro.runtime.metrics import scope
+from repro.runtime.metrics import count, scope
 
 from . import ref
-from .jet_attention import (jet_attention_scores_pallas,
+from .jet_attention import (FLASH_BLOCK_K, flash_jet_bwd_pallas,
+                            jet_attention_scores_pallas,
                             jet_flash_attention_pallas, jet_rms_norm_pallas)
 from .jet_dense import jet_dense_pallas
 from .tanh_jet import KERNEL_ACTS as _KERNEL_ACTS
@@ -237,8 +238,12 @@ def jet_attention_scores(q_coeffs: jnp.ndarray, k_coeffs: jnp.ndarray,
 # tiled flash-jet attention: the whole block (scores + masked softmax +
 # value contraction + output projection) in ONE launch with an online-
 # softmax recurrence over KV blocks generalized to the coefficient axis --
-# the "flash_attention" registry entry.  Backward recomputes through the
+# the "flash_attention" registry entry.  A sequence that fits one key tile
+# takes the one-tile backward kernel ``flash_jet_bwd`` (recompute and
+# closed-form reverse in VMEM); a longer one recomputes through the
 # straight-line reference (materializing, but only under differentiation).
+# Each dispatch is counted at trace time: ``kernel.flash_jet_bwd`` or
+# ``kernel.flash_jet_bwd.fallback``.
 # ---------------------------------------------------------------------------
 
 def _flash_attention_impl(q, k, v, wo, scale, mask):
@@ -261,8 +266,14 @@ def _flash_attention_fwd(q, k, v, wo, scale, mask):
 
 @scope("kernel.flash_attention.bwd")
 def _flash_attention_bwd(scale, mask, res, g):
-    from repro.core.modules import attention_mask
     q, k, v, wo = res
+    if q.shape[-2] <= FLASH_BLOCK_K:
+        count("kernel.flash_jet_bwd", 1)
+        kind, window = mask
+        return flash_jet_bwd_pallas(q, k, v, wo, g, scale, mask=kind,
+                                    window=window, interpret=not _on_tpu())
+    count("kernel.flash_jet_bwd.fallback", 1)
+    from repro.core.modules import attention_mask
     dense = attention_mask(mask, q.shape[-2])
     _, vjp = jax.vjp(
         lambda qq, kk, vv, ww: ref.jet_flash_attention_ref(

@@ -134,7 +134,7 @@ def wave_jet_bwd_tile(c, g, w1, w2):
     return dc, s * qe + co * qo, co * qe - s * qo
 
 
-def _over_chunks(bb: int, sub: int, body, init):
+def over_chunks(bb: int, sub: int, body, init):
     """``body(rows, carry)`` over the block's rows ``sub`` at a time (one
     call over all of them when ``sub`` does not divide ``bb``)."""
     if sub >= bb or bb % sub:
@@ -155,7 +155,7 @@ def _forward_kernel(w_ref, c_ref, o_ref, *, sub):
             o_ref[k, rows, :] = out[k].astype(o_ref.dtype)
         return carry
 
-    _over_chunks(bb, sub, chunk, 0)
+    over_chunks(bb, sub, chunk, 0)
 
 
 def _backward_kernel(w_ref, c_ref, g_ref, dc_ref, dw_ref, *, rows, sub):
@@ -181,7 +181,7 @@ def _backward_kernel(w_ref, c_ref, g_ref, dc_ref, dw_ref, *, rows, sub):
 
     n = bb if sub >= bb or bb % sub else sub
     zero = jnp.zeros((n, width), acc)
-    gs, gc = _over_chunks(bb, sub, chunk, (zero, zero))
+    gs, gc = over_chunks(bb, sub, chunk, (zero, zero))
     dw_ref[0, 0:1, :] = jnp.sum(gs, axis=0, keepdims=True)
     dw_ref[0, 1:2, :] = jnp.sum(gc, axis=0, keepdims=True)
 

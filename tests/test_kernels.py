@@ -10,11 +10,13 @@ import pytest
 from repro.core import jet as J
 from repro.kernels import ops, ref
 from repro.kernels.bell_tables import fdb_terms, tanh_poly_rows
-from repro.kernels.jet_attention import (jet_attention_scores_pallas,
+from repro.kernels.jet_attention import (FLASH_BLOCK_K, flash_jet_bwd_pallas,
+                                         jet_attention_scores_pallas,
                                          jet_flash_attention_pallas,
                                          jet_rms_norm_pallas)
 from repro.kernels.jet_dense import jet_dense_pallas
 from repro.kernels.tanh_jet import act_jet_pallas
+from repro.runtime import metrics
 
 SHAPES = [(4, 24), (32, 130), (17, 257)]
 ORDERS = [1, 3, 6]
@@ -278,8 +280,8 @@ def test_flash_attention_ref_matches_core_jet_algebra():
 
 
 def test_flash_attention_grads_flow_through_reference_recompute():
-    """custom_vjp backward of ops.jet_flash_attention recomputes through the
-    ref path and matches autodiff of the ref directly."""
+    """The custom_vjp backward of ops.jet_flash_attention (the one-tile
+    ``flash_jet_bwd`` kernel at T = 5) matches autodiff of the ref."""
     q, k, v, wo, scale = _flash_case(2, 1, 2, 5, 4, 3, seed=11)
     q, k, v, wo = (x.astype(jnp.float64) for x in (q, k, v, wo))
 
@@ -289,6 +291,85 @@ def test_flash_attention_grads_flow_through_reference_recompute():
     g_ker = jax.grad(loss(lambda a, b, c, w: ops.jet_flash_attention(
         a, b, c, w, scale, mask="causal")), argnums=(0, 1, 2, 3))(q, k, v, wo)
     keep = _dense_keep("causal", 0, 5)
+    g_ref = jax.grad(loss(lambda a, b, c, w: ref.jet_flash_attention_ref(
+        a, b, c, w, scale, mask=keep)), argnums=(0, 1, 2, 3))(q, k, v, wo)
+    for a, b in zip(g_ker, g_ref):
+        np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-8)
+
+
+def _flash_bwd_case(order, bsz, heads, t, dtype, seed):
+    q, k, v, wo, scale = _flash_case(order, bsz, heads, t, 4, 3, seed=seed)
+    g = jax.random.normal(jax.random.PRNGKey(seed + 1),
+                          (order + 1, bsz, t, 3), jnp.float32)
+    return tuple(x.astype(dtype) for x in (q, k, v, wo, g)), scale
+
+
+def _reference_vjp(q, k, v, wo, g, scale, mask, window):
+    keep = _dense_keep(mask, window, q.shape[-2])
+    _, vjp = jax.vjp(lambda a, b, c, w: ref.jet_flash_attention_ref(
+        a, b, c, w, scale, mask=keep), q, k, v, wo)
+    return vjp(g)
+
+
+# a batch of 7 in blocks of 4: the ragged last block's rows past the batch
+# must stay out of dwo
+@pytest.mark.parametrize("order,t,heads", [(0, 5, 2), (1, 8, 1), (2, 1, 2),
+                                           (3, 5, 2), (4, 8, 2)])
+@pytest.mark.parametrize("mask,window", [("none", 0), ("causal", 0),
+                                         ("local", 2)])
+def test_flash_jet_bwd_matches_reference_vjp(order, t, heads, mask, window):
+    """The one-tile backward kernel's (dq, dk, dv, dwo) against jax.vjp
+    through the straight-line ref, in f64."""
+    (q, k, v, wo, g), scale = _flash_bwd_case(order, 7, heads, t,
+                                              jnp.float64, seed=3 + order)
+    got = flash_jet_bwd_pallas(q, k, v, wo, g, scale, mask=mask,
+                               window=window, block_b=4, interpret=True)
+    want = _reference_vjp(q, k, v, wo, g, scale, mask, window)
+    for a, b in zip(got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(a, b, rtol=1e-10, atol=1e-10)
+
+
+@pytest.mark.parametrize("mask,window", [("none", 0), ("causal", 0),
+                                         ("local", 2)])
+def test_flash_jet_bwd_f32(mask, window):
+    """In f32 the kernel stays within 1e-5 of the ref's f64 VJP."""
+    (q, k, v, wo, g), scale = _flash_bwd_case(3, 7, 2, 5, jnp.float32,
+                                              seed=21)
+    got = flash_jet_bwd_pallas(q, k, v, wo, g, scale, mask=mask,
+                               window=window, block_b=4, interpret=True)
+    want = _reference_vjp(*(x.astype(jnp.float64) for x in (q, k, v, wo, g)),
+                          scale, mask, window)
+    for a, b in zip(got, want):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("t,path", [(5, "kernel.flash_jet_bwd"),
+                                    (FLASH_BLOCK_K + 1,
+                                     "kernel.flash_jet_bwd.fallback")])
+def test_flash_attention_backward_dispatch_by_key_tile(t, path):
+    """A sequence within one key tile takes the backward kernel, a longer
+    one the reference recompute; each dispatch counts once under its own
+    counter, and both paths match autodiff of the ref."""
+    q, k, v, wo, scale = _flash_case(1, 1, 1, t, 4, 3, seed=5)
+    q, k, v, wo = (x.astype(jnp.float64) for x in (q, k, v, wo))
+    names = ("kernel.flash_jet_bwd", "kernel.flash_jet_bwd.fallback")
+
+    def counted():
+        c = metrics.snapshot()["counter"]
+        return [c.get(n, (0, 0.0))[0] for n in names]
+
+    def loss(f):
+        return lambda a, b, c, w: jnp.sum(f(a, b, c, w) ** 2)
+
+    before = counted()
+    g_ker = jax.grad(loss(lambda a, b, c, w: ops.jet_flash_attention(
+        a, b, c, w, scale, mask="causal")), argnums=(0, 1, 2, 3))(q, k, v, wo)
+    after = counted()
+    assert [a - b for a, b in zip(after, before)] == \
+        [int(n == path) for n in names]
+    keep = _dense_keep("causal", 0, t)
     g_ref = jax.grad(loss(lambda a, b, c, w: ref.jet_flash_attention_ref(
         a, b, c, w, scale, mask=keep)), argnums=(0, 1, 2, 3))(q, k, v, wo)
     for a, b in zip(g_ker, g_ref):

@@ -26,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core.engines import NTPEngine
 from repro.core.network import DenseMLP
 from repro.kernels import ops
-from repro.kernels.jet_attention import (jet_flash_attention_pallas,
+from repro.kernels.jet_attention import (flash_jet_bwd_pallas,
+                                         jet_flash_attention_pallas,
                                          jet_rms_norm_pallas)
 from repro.kernels.jet_dense import jet_dense_pallas
 from repro.kernels.tanh_jet import act_jet_pallas
@@ -119,6 +120,26 @@ WAVE_WIDTHS = (32, 256, 512)
 WAVE_ROWS = BATCH * 5 + 40
 PALLAS_CALL = re.compile(r'^\s*(?:ROOT )?%(\S+) = .*'
                          r'custom_call_target="tpu_custom_call"', re.M)
+
+
+# PINNsFormer's attention: 2 heads of 16 over d_model 32, 5 tokens; a batch
+# of sequences that fills no whole block, so the masked weight sum compiles
+PFNS_SEQS = 1250
+
+
+@pytest.mark.parametrize("mask", ("none", "causal", "local"))
+@pytest.mark.parametrize("order", (1, 2, 3, 4))
+def test_flash_jet_bwd_compiles(one_chip, order, mask):
+    """The one-tile flash-jet backward at PINNsFormer's shapes, one launch
+    named after its ``pallas_call``."""
+    qkv = (order + 1, PFNS_SEQS, HEADS, 5, 16)
+    text = compile_for_chip(
+        lambda q, k, v, wo, g: flash_jet_bwd_pallas(
+            q, k, v, wo, g, 0.25, mask=mask, window=2, interpret=False),
+        qkv, qkv, qkv, (HEADS, 16, 32), (order + 1, PFNS_SEQS, 5, 32),
+        sharding=one_chip)
+    calls = PALLAS_CALL.findall(text)
+    assert [n.rsplit(".", 1)[0] for n in calls] == ["flash_jet_bwd"], calls
 
 
 @pytest.mark.parametrize("order", (1, 2, 3, 4))
@@ -303,10 +324,19 @@ PFNS_SCOPES = ("net.self_attn", "net.cross_attn", "net.wave", "net.seq",
 PFNS_WAVES = 12
 
 
+def _flash_bwd_dispatches():
+    """(kernel, reference) dispatches of the flash-jet backward so far."""
+    from repro.runtime import metrics
+    counters = metrics.snapshot()["counter"]
+    return [counters.get(name, (0, 0.0))[0] for name in
+            ("kernel.flash_jet_bwd", "kernel.flash_jet_bwd.fallback")]
+
+
 def test_pinnsformer_step_runs_both_kernels(one_chip, monkeypatch):
     """The ``ntp/pallas`` train step of a PINNsFormer calls the ``jet_dense``,
     ``jet_flash_attention`` and ``wave_jet`` kernels compiled (one
-    ``wave_jet`` forward and one ``wave_jet_bwd`` launch per Wave jet), and
+    ``wave_jet`` forward and one ``wave_jet_bwd`` launch per Wave jet, one
+    ``flash_jet_bwd`` launch per attention block, counted at trace time), and
     its operations carry the network's scopes and the kernels' backward
     scopes."""
     if NS_SHAPED_OP not in operator_names():
@@ -329,12 +359,17 @@ def test_pinnsformer_step_runs_both_kernels(one_chip, monkeypatch):
                                            sharding=one_chip),
             (res.params, adam_init(res.params),
              jnp.zeros((n_domain, 3), jnp.float32)))
+        before = _flash_bwd_dispatches()
         text = res.train_step.lower(*args).compile().as_text()
+    # tracing the step dispatches the kernel once per attention block
+    assert [a - b for a, b in zip(_flash_bwd_dispatches(), before)] == [2, 0]
     calls = [n for n, kind, _ in _scoped_ops(text) if kind == "custom-call"]
     # embed, 3 + 3 FF per layer, 3 head maps; one flash launch per layer
     assert sum(n.startswith("jet_dense") for n in calls) == 1 + 2 * 6 + 3
     assert sum(n.startswith("jet_flash_attention") for n in calls) == 2
     kernels = [n.rsplit(".", 1)[0] for n in PALLAS_CALL.findall(text)]
+    # each attention block's backward is the one-tile kernel (T = 5)
+    assert kernels.count("flash_jet_bwd") == 2
     assert kernels.count("wave_jet") == PFNS_WAVES
     assert kernels.count("wave_jet_bwd") == PFNS_WAVES
     named = _all_names(text)
